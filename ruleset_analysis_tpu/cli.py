@@ -467,7 +467,7 @@ def _cmd_run(args: argparse.Namespace) -> int:
         except ImportError as e:
             print(f"error: tpu backend unavailable ({e})", file=sys.stderr)
             return 1
-        enable_persistent_cache()  # skip the ~15s recompile on repeat runs
+        enable_persistent_cache()  # skip the step recompile on repeat runs
         # convert-fleet manifests expand to their shard lists first: the
         # multi-file WireReader concatenates shard payloads and counts
         # resume offsets in stored-row units, so a fleet output is one
@@ -879,10 +879,12 @@ def _cmd_serve(args: argparse.Namespace) -> int:
         print(f"error: {e}", file=sys.stderr)
         return 2
     try:
+        from .runtime.compcache import enable_persistent_cache
         from .runtime.serve import ServeDriver  # deferred: imports JAX
     except ImportError as e:
         print(f"error: tpu backend unavailable ({e})", file=sys.stderr)
         return 1
+    enable_persistent_cache()
     if args.trace_out or args.metrics_out:
         from .runtime import obs
 

@@ -66,6 +66,15 @@ class WireCorrupt(AnalysisError):
     rows of a corrupted production input."""
 
 
+class ChipBindingError(AnalysisError):
+    """Several processes on one host would each need a chip of their own.
+
+    Raised before any of them starts, by the multi-process modes that
+    cannot bind one distinct chip per process on a TPU host (see
+    parallel/distributed.py ``check_one_chip_per_process``).
+    """
+
+
 class ReformBudgetExhausted(AnalysisError):
     """The elastic supervisor used up ``--max-reforms`` re-formations."""
 

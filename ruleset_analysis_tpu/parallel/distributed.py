@@ -17,9 +17,13 @@ the SPMD program); multi-host init itself needs a real cluster.
 
 from __future__ import annotations
 
+import os
+
 import jax
 import numpy as np
 from jax.sharding import Mesh
+
+from ..errors import ChipBindingError
 
 
 def init_distributed(
@@ -40,38 +44,63 @@ def init_distributed(
     long and every surviving process's pending collective aborts with an
     error instead of hanging — the rebuilt analog of YARN failing a job
     whose task died (SURVEY.md §6 failure detection).  None keeps JAX's
-    default (100s).  Older jax releases take no such parameter; it is
-    silently dropped there (the elastic supervisor's own stale-heartbeat
-    watchdog — runtime/elastic.py — then provides the detection bound,
-    which is why recovery stays bounded-time on every supported jax).
+    default (100s).
 
     ``initialization_timeout`` bounds cluster FORMATION: a member listed
     in a re-formation plan that dies before joining would otherwise hold
     everyone in initialize() for jax's 300 s default.
-    """
-    import inspect
 
+    A loopback coordinator means every process runs on this host; see
+    :func:`check_one_chip_per_process`.
+    """
+    if coordinator_address and num_processes and is_loopback(
+        coordinator_address.rpartition(":")[0]
+    ):
+        check_one_chip_per_process(
+            num_processes, "jax.distributed processes behind a loopback "
+            f"coordinator ({coordinator_address})",
+        )
     # Cross-process collectives on the CPU backend (the fake-mesh test
-    # idiom and any CPU-host deployment) need a CPU collectives library;
-    # 0.4.x-era jax defaults to "none" and fails every multi-process
-    # computation with "not implemented on the CPU backend".  Newer jax
-    # defaults this on (or renames the option) — failures are ignored.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
+    # idiom and any CPU-host deployment) need a CPU collectives library.
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     kw = {}
     if heartbeat_timeout_seconds is not None:
         kw["heartbeat_timeout_seconds"] = heartbeat_timeout_seconds
     if initialization_timeout is not None:
         kw["initialization_timeout"] = initialization_timeout
-    supported = inspect.signature(jax.distributed.initialize).parameters
-    kw = {k: v for k, v in kw.items() if k in supported}
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
         process_id=process_id,
         **kw,
+    )
+
+
+def is_loopback(host: str) -> bool:
+    """True for a host name or address that only this machine answers."""
+    host = host.strip("[]")
+    return host == "localhost" or host.startswith("127.") or host == "::1"
+
+
+def check_one_chip_per_process(n_processes: int, what: str) -> None:
+    """Refuse ``n_processes`` JAX processes on one host unless they use the CPU.
+
+    A process that starts the TPU backend takes every chip of its host,
+    so a second process on the same host fails or hangs at start-up.
+    Nothing in this repo binds one distinct chip to each process, so
+    such a mode is refused by name.  The platform is read from
+    ``JAX_PLATFORMS`` without starting a backend in this process, which
+    may itself be the one about to spawn the others.
+    """
+    platform = os.environ.get("JAX_PLATFORMS", "").split(",")[0]
+    if n_processes <= 1 or platform == "cpu":
+        return
+    raise ChipBindingError(
+        f"{what}: {n_processes} processes on one host would each need a "
+        f"chip of their own (JAX_PLATFORMS={platform or 'unset'}), and "
+        "nothing binds one to each.  Run one process per host, use the "
+        "in-process thread mode, or set JAX_PLATFORMS=cpu for a CPU "
+        "rehearsal."
     )
 
 

@@ -43,24 +43,9 @@ from ..runtime import devprof
 _U32 = jnp.uint32
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking off.
-
-    ``jax.shard_map`` (kwarg ``check_vma``) landed after 0.4.x; older
-    installs ship ``jax.experimental.shard_map`` (kwarg ``check_rep``).
-    Both compile the identical program here — the collectives are written
-    explicitly, so the replication checker adds nothing but version skew.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
+#: shard_map with replication checking off: the collectives are written
+#: explicitly, so the checker adds nothing here
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def _merge_tail(

@@ -10,7 +10,7 @@ the "attribute before you optimize" discipline the scatter-wall attack
 
 - **Semantic naming.**  Every register-update stage in ``ops/`` and the
   dispatch seams in ``parallel/step.py`` trace under ``jax.named_scope``
-  labels (the ``ra.*`` taxonomy: :data:`STAGES`).  Scopes ride HLO op
+  labels (the ``ra.*`` vocabulary: :data:`STAGES`).  Scopes ride HLO op
   *metadata* (``op_name``) through XLA's optimizer, so fusions — even
   renumbered ones — carry the stages they fused.  Trace-time only:
   zero runtime cost, bit-identical outputs.
@@ -58,7 +58,7 @@ import time
 
 from . import faults, obs
 
-# The stage taxonomy (DESIGN §14) is single-sourced in
+# The stage vocabulary (DESIGN §14) is single-sourced in
 # ruleset_analysis_tpu/stages.py — this module, tools/trace_attrib.py,
 # and the static lint plane (verify/) all import the SAME tuple, so the
 # three consumers can never drift.  Re-exported here because this module

@@ -67,7 +67,9 @@ from ..hostside import pack as pack_mod
 from ..hostside.listener import offset_listen_spec
 from ..models import pipeline
 from ..ops.topk import TopKTracker
-from ..parallel.distributed import pack_epoch_payload, unpack_epoch_payload
+from ..parallel.distributed import (
+    check_one_chip_per_process, pack_epoch_payload, unpack_epoch_payload,
+)
 from . import checkpoint as ckpt
 from . import epochstore, faults, flightrec, obs, retrypolicy
 from .lease import EpochSpool, SupervisorLease
@@ -626,6 +628,12 @@ class DistServeDriver:
             raise AnalysisError(
                 "serve needs at least one --listen spec "
                 "(udp:HOST:PORT, tcp:HOST:PORT, or tail:PATH)"
+            )
+        if dscfg.workers == "process":
+            # every spawned host process steps its own registers on the
+            # device, and all of them run on this machine
+            check_one_chip_per_process(
+                dscfg.ladder_max, "serve --dist-workers process"
             )
         self.prefix = ruleset_prefix
         self.cfg = cfg

@@ -302,6 +302,16 @@ class ElasticSupervisor:
         self.coordinator_host = coordinator_host or os.environ.get(
             "RA_ELASTIC_HOST", "127.0.0.1"
         )
+        from ..parallel.distributed import (
+            check_one_chip_per_process, is_loopback,
+        )
+
+        if is_loopback(self.coordinator_host):
+            # every launcher's generation worker runs on this host
+            check_one_chip_per_process(
+                self.n_procs, f"--elastic launchers behind a loopback "
+                f"coordinator ({self.coordinator_host})",
+            )
         self.meter = RecoveryMeter()
         self.reforms_used = 0
         self.final_world: list[int] | None = None

@@ -281,7 +281,7 @@ class DispatchTimer:
         Every dispatch also records a ``step.dispatch`` trace span when
         the observability plane is armed — this method already wraps
         every device dispatch of both stream drivers, so one hook here
-        covers the whole step taxonomy.  Disarmed cost past the first
+        covers the whole step vocabulary.  Disarmed cost past the first
         two dispatches: one None-check.
         """
         lst = self._t.setdefault(kind, [])

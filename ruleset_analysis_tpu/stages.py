@@ -1,20 +1,20 @@
-"""The ``ra.*`` named-scope stage taxonomy (DESIGN §14) — ONE source.
+"""The ``ra.*`` named-scope stage vocabulary (DESIGN §14) — ONE source.
 
 Every register-update stage in ``ops/`` and the dispatch seams in
 ``parallel/step.py`` trace under ``jax.named_scope`` labels from this
-taxonomy.  Scopes ride HLO op *metadata* (``op_name``) through XLA's
+vocabulary.  Scopes ride HLO op *metadata* (``op_name``) through XLA's
 optimizer, so profiler fusions — even renumbered ones — carry the
 stages they fused; they also land on every jaxpr equation's
 ``source_info.name_stack``, which is how the static lint plane
 (``verify/``) proves scope coverage without a device.
 
-Three consumers import this module so the taxonomy can never drift
+Three consumers import this module so the vocabulary can never drift
 between them:
 
 - ``runtime/devprof.py`` — in-process capture windows classify profiled
   events by these stages;
 - ``tools/trace_attrib.py`` — offline trace attribution flags ``ra.*``
-  tokens that are NOT in the taxonomy (a scope someone added without
+  tokens that are NOT in the vocabulary (a scope someone added without
   registering it here);
 - ``ruleset_analysis_tpu/verify`` — the jaxpr linter requires every
   register-update primitive to attribute to exactly one member stage

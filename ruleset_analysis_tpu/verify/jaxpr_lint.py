@@ -142,17 +142,11 @@ class ProgramLint:
 #: call-like primitives: param key holding the inner jaxpr; invars map
 #: positionally onto the inner invars (after the ClosedJaxpr's consts).
 _CALL_PRIMS = {
-    "pjit": "jaxpr",
+    "jit": "jaxpr",
     "closed_call": "call_jaxpr",
-    "core_call": "call_jaxpr",
-    "xla_call": "call_jaxpr",
-    "named_call": "call_jaxpr",
-    "remat": "jaxpr",
-    "checkpoint": "jaxpr",
     "remat2": "jaxpr",
     "custom_jvp_call": "call_jaxpr",
     "custom_vjp_call": "call_jaxpr",
-    "custom_vjp_call_jaxpr": "fun_jaxpr",
     "shard_map": "jaxpr",
 }
 
@@ -209,7 +203,7 @@ class _Walker:
         elif stage not in STAGES:
             self._find(
                 "scope", "unregistered-stage", eqn, "violation",
-                f"scope {stage!r} is not in the stages.py taxonomy",
+                f"scope {stage!r} is not in the stages.py vocabulary",
             )
 
     def _check_add_sink(self, eqn, info: Info, what: str):

@@ -862,12 +862,9 @@ def test_chaos_autoscale_seam(seed, serve_corpus, tmp_path):
 
 
 def _launcher_env(n_local_devices: int) -> dict:
-    sys.path.insert(0, _REPO)
-    from __graft_entry__ import scrubbed_cpu_env
+    from cpuenv import cpu_env
 
-    env = scrubbed_cpu_env(n_local_devices)
-    env["RA_TEST_REEXEC"] = "1"
-    return env
+    return cpu_env(n_local_devices)
 
 
 @pytest.fixture(scope="module")

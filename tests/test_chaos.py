@@ -649,15 +649,12 @@ def _spawn_elastic_chaos(td, prefix, shards, victim_plan, victim_tag,
     import subprocess
     import sys
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, repo)
-    from __graft_entry__ import scrubbed_cpu_env
+    from cpuenv import REPO, cpu_env
 
     eldir = str(td / "eldir")
     procs = []
     for pid in range(4):
-        env = scrubbed_cpu_env(2)
-        env["RA_TEST_REEXEC"] = "1"
+        env = cpu_env(2)
         if pid == victim_tag:
             env[faults.ENV_VAR] = victim_plan
         procs.append(
@@ -668,7 +665,7 @@ def _spawn_elastic_chaos(td, prefix, shards, victim_plan, victim_tag,
                  "--num-processes", "4", "--process-id", str(pid),
                  "--batch-size", "64", "--checkpoint-every", "2",
                  "--json", "--out", str(td / f"rep{pid}.json")],
-                env=env, cwd=repo,
+                env=env, cwd=REPO,
                 stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
             )
         )

@@ -5,7 +5,7 @@ Assertion tiers:
 - **semantic naming** — every ops stage traces under its ``ra.*``
   named scope: the scope token is present in the optimized HLO text of
   a tiny jit of each stage, and the full parallel step program's static
-  stage table covers the whole taxonomy;
+  stage table covers the whole vocabulary;
 - **capture windows** — ``devprof.arm`` + a driver run produce a
   well-formed ``devprof.json`` across sync/prefetch x text/wire x
   v4/v6: the requested number of dispatches profiled, >= 90% of
@@ -285,7 +285,7 @@ def test_capture_sync_text_v4(corpus, tmp_path):
     rep = run_stream_file(packed, [log], _cfg(depth=0), native=False)
     dp = rep.totals["devprof"]
     _assert_capture_well_formed(dp, 2)
-    # the full step program exercises the whole v4 stage taxonomy
+    # the full step program exercises the whole v4 stage vocabulary
     static = dp["programs"]["step.flat"]["stages_static"]
     for stage in ("ra.unpack", "ra.match", "ra.counts", "ra.cms",
                   "ra.hll", "ra.talk", "ra.topk", "ra.merge"):

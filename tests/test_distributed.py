@@ -31,12 +31,9 @@ def _free_port() -> int:
 
 
 def _worker_env(n_local_devices: int) -> dict:
-    sys.path.insert(0, _REPO)
-    from __graft_entry__ import scrubbed_cpu_env
+    from cpuenv import cpu_env
 
-    env = scrubbed_cpu_env(n_local_devices)
-    env["RA_TEST_REEXEC"] = "1"
-    return env
+    return cpu_env(n_local_devices)
 
 
 def _spawn_and_check(argvs, n_local_devices):

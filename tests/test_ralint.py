@@ -358,7 +358,7 @@ def test_expected_refusal_helper_matches_config_fields():
     assert expected_weighted_refusal(ProgramSpec(kind="flat")) is None
 
 
-def test_stages_taxonomy_single_source():
+def test_stages_vocabulary_single_source():
     """devprof re-exports the stages.py tuple — identity, not a copy."""
     from ruleset_analysis_tpu import stages
     from ruleset_analysis_tpu.runtime import devprof

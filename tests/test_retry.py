@@ -204,11 +204,9 @@ def test_backoff_deterministic_same_seed_and_shape():
 def test_backoff_deterministic_across_processes():
     """Same seed -> same jitter sequence in a FRESH interpreter (no
     PYTHONHASHSEED dependence — the acceptance property)."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, repo)
-    from __graft_entry__ import scrubbed_cpu_env
+    from cpuenv import REPO, cpu_env
 
-    env = scrubbed_cpu_env(1)
+    env = cpu_env(1)
     env["PYTHONHASHSEED"] = "random"
     out = subprocess.run(
         [sys.executable, "-c",
@@ -216,7 +214,7 @@ def test_backoff_deterministic_across_processes():
          "from ruleset_analysis_tpu.runtime import retrypolicy\n"
          "print(json.dumps(retrypolicy.backoff_schedule("
          "'checkpoint.save', 6, seed=42)))"],
-        env=env, cwd=repo, capture_output=True, text=True, timeout=120,
+        env=env, cwd=REPO, capture_output=True, text=True, timeout=120,
     )
     assert out.returncode == 0, out.stderr[-2000:]
     theirs = json.loads(out.stdout.strip())

@@ -20,7 +20,7 @@ Assertion tiers:
   config-time refusal (CLI exit 2), while weighted (RAWIREv3) inputs
   are ACCEPTED under sorted everywhere (weight-linear by construction);
 - **attribution** — the sorts trace under the ``ra.sort`` named scope
-  and the taxonomy knows the stage.
+  and the vocabulary knows the stage.
 
 The corpus deliberately reuses test_obs/test_devprof's ruleset + sketch
 geometry (synth seed 7, 3 ACLs x 8 rules, batch 512, cms 1<<10 x 2,
@@ -226,7 +226,7 @@ def test_composite_overflow_falls_back_value_identically(monkeypatch):
 
 
 def test_sorted_scopes_in_hlo():
-    """The sorts trace under ra.sort; devprof's taxonomy knows the stage."""
+    """The sorts trace under ra.sort; devprof's vocabulary knows the stage."""
     from ruleset_analysis_tpu.runtime import devprof
 
     assert "ra.sort" in devprof.STAGES

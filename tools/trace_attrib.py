@@ -6,7 +6,7 @@ Usage:
 
 Defaults to every ``*.trace.json.gz`` under ``profiles/``.  For each
 process track, prints total duration by **semantic stage** where the
-events carry ``jax.named_scope`` labels (the ``ra.*`` taxonomy every
+events carry ``jax.named_scope`` labels (the ``ra.*`` vocabulary every
 register-update stage traces under since PR 8 — DESIGN §14), falling
 back to the raw event name where they don't (pre-scope captures, host
 runtime events).  The classifier is IMPORTED from
@@ -34,7 +34,7 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from ruleset_analysis_tpu.runtime.devprof import classify_event_name  # noqa: E402
-from ruleset_analysis_tpu.stages import STAGES  # noqa: E402  (the ONE taxonomy)
+from ruleset_analysis_tpu.stages import STAGES  # noqa: E402  (the ONE vocabulary)
 
 
 def load_events(path: str) -> list[dict]:
@@ -68,7 +68,7 @@ def attribute(path: str, top: int = 20) -> dict:
         label = stage if stage is not None else e.get("name", "?")[:90]
         if stage is not None and stage not in STAGES:
             # syntactically an ra.* scope, but absent from the registered
-            # taxonomy (stages.py) — someone added a scope without
+            # vocabulary (stages.py) — someone added a scope without
             # registering it; the static linter flags the same drift
             unregistered.add(stage)
         key = (names.get(e["pid"], str(e["pid"])), label)
@@ -103,7 +103,7 @@ def render(a: dict) -> str:
         )
     if a.get("unregistered_stages"):
         out.append(
-            "  WARNING: ra.* scopes not in the registered taxonomy "
+            "  WARNING: ra.* scopes not in the registered vocabulary "
             f"(stages.py): {', '.join(a['unregistered_stages'])}"
         )
     for r in a["rows"]:
