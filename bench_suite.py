@@ -1155,7 +1155,7 @@ def bench_sustained() -> dict:
 
     ``RA_SUSTAINED_LINES`` overrides the volume (default 1e8; the
     acceptance floor).  A small warm run first fills the in-process jit
-    cache; the driver's ``compile_sec`` then prices any residue.
+    cache; the driver's ``compile.sec`` then prices any residue.
     """
     import os
     import tempfile
@@ -1214,7 +1214,7 @@ def bench_sustained() -> dict:
                 return json.load(f)
 
         # warm: fills the memoized step-builder + jit caches in-process,
-        # so the measured run's compile_sec is the honest residue
+        # so the measured run's compile.sec is the honest residue
         t0 = time.perf_counter()
         run_cli(warm_path, os.path.join(d, "warm.json"))
         warm_sec = time.perf_counter() - t0
@@ -1239,7 +1239,7 @@ def bench_sustained() -> dict:
             "lines_per_sec_incl_compile": totals["lines_per_sec"],
             "sustained_lines_per_sec": sustained,
             "sustained_lines_per_min": round(sustained * 60, 1),
-            "compile_sec": totals["compile_sec"],
+            "compile_sec": totals["compile"]["sec"],
             "warm_run_sec": round(warm_sec, 2),
             "corpus_synth_sec": round(t_synth, 1),
             "ingest": totals.get("ingest"),
@@ -1371,7 +1371,7 @@ def bench_steptrace() -> dict:
       armed/disarmed sustained ratio with the capture pause priced
       apart (``window_wall_sec`` — profiling a step on XLA:CPU emits an
       event per scatter-loop iteration, 10-50x the plain step; the same
-      separation discipline as compile_sec, r6), budget >= 0.98
+      separation discipline as compile.sec, r6), budget >= 0.98
       OUTSIDE the window; the raw including-pause ratio is reported
       alongside, never hidden.  Plus the attributed fraction
       (acceptance >= 0.90, remainder explicit), the per-stage table —
@@ -1465,9 +1465,9 @@ def bench_steptrace() -> dict:
     # lines_this_run / (elapsed - compile - window_wall)
     t_on = rep_on["totals"]
     pause = cap.get("window_wall_sec") or 0.0
-    ex_window = t_on["elapsed_sec"] - t_on["compile_sec"] - pause
+    ex_window = t_on["elapsed_sec"] - t_on["compile"]["sec"] - pause
     on_ex = (
-        round(t_on["throughput"]["lines"] / ex_window, 1)
+        round(t_on["lines_total"] / ex_window, 1)
         if ex_window > 0
         else 0.0
     )
@@ -1836,7 +1836,7 @@ def bench_coalesce() -> dict:
             write_corpus(wp, skew)
             # warm fills the jit caches (off-path shapes); the coalesced
             # bucket shapes compile inside their own measured run's
-            # compile_sec, which the sustained rate already excludes
+            # compile.sec, which the sustained rate already excludes
             run_cli(wp, "off", os.path.join(d, "warm.json"))
             rep_off = run_cli(wp, "off", os.path.join(d, f"{name}-off.json"))
             rep_on = run_cli(wp, "on", os.path.join(d, f"{name}-on.json"))

@@ -232,7 +232,7 @@ def run_tpu(corpus: Corpus, size: Size, name: str, logs: list[str],
     t = rep["totals"]
     say(
         f"{name}: matches the oracle exactly | smoke timings, not benchmark "
-        f"numbers: wall {wall:.2f}s, compile_sec {t.get('compile_sec')}, "
+        f"numbers: wall {wall:.2f}s, compile {t.get('compile')}, "
         f"lines_per_sec {t.get('lines_per_sec')}, sustained_lines_per_sec "
         f"{t.get('sustained_lines_per_sec')}, chunks {t.get('chunks')}"
     )
@@ -457,7 +457,7 @@ def mesh_phase(corpus: Corpus, size: Size, ref: dict, devices) -> None:
         say(
             f"{n}-chip run: matches the oracle exactly | smoke timings, not "
             f"benchmark numbers: wall {time.perf_counter() - t0:.2f}s, "
-            f"compile_sec {t.get('compile_sec')}, sustained_lines_per_sec "
+            f"compile {t.get('compile')}, sustained_lines_per_sec "
             f"{t.get('sustained_lines_per_sec')}"
         )
     many = len(devices)

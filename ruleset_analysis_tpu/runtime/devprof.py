@@ -586,7 +586,7 @@ class DevprofCapture:
             "warmup": self.warmup,
             "steps_profiled": steps_profiled,
             #: the bounded pause the live profiler cost this run — price
-            #: it apart from the sustained rate, like compile_sec
+            #: it apart from the sustained rate, like totals.compile
             "window_wall_sec": (
                 round(self._window_wall, 3)
                 if self._window_wall is not None

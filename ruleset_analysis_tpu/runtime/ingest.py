@@ -66,8 +66,11 @@ class _Counters:
 class IngestStats:
     """Per-stage overlap accounting for one prefetched stream.
 
-    ``produce_sec`` is time the producer spent inside the inner iterator
-    plus the pack transform (the parse/H2D-issue stage);
+    ``parse_sec`` is time the producer spent inside the inner iterator
+    (the parse, or the wire reader's block reads) — what the
+    ``ingest.produce`` spans cover; ``pack_sec`` is the pack transform
+    (coalescing, bit-pack and the async sharded ``device_put`` issue) —
+    what ``ingest.pack`` covers; ``produce_sec`` is their sum.
     ``backpressure_sec`` is producer time blocked on a full queue (the
     device is the bottleneck); ``starved_sec`` is consumer time blocked
     on an empty queue (the parse is the bottleneck).  The report totals
@@ -76,15 +79,23 @@ class IngestStats:
     """
 
     def __init__(self):
-        self.produce_sec = 0.0
+        self.parse_sec = 0.0
+        self.pack_sec = 0.0
         self.backpressure_sec = 0.0
         self.starved_sec = 0.0
         self.batches = 0
 
+    @property
+    def produce_sec(self) -> float:
+        return self.parse_sec + self.pack_sec
+
     def to_dict(self) -> dict:
+        parse, pack = round(self.parse_sec, 4), round(self.pack_sec, 4)
         return {
             "batches": self.batches,
-            "produce_sec": round(self.produce_sec, 4),
+            "produce_sec": round(parse + pack, 4),
+            "parse_sec": parse,
+            "pack_sec": pack,
             "backpressure_sec": round(self.backpressure_sec, 4),
             "starved_sec": round(self.starved_sec, 4),
         }
@@ -104,21 +115,19 @@ class _Pump:
             target=self._produce, name="ra-ingest-producer", daemon=True
         )
 
-    def _put(self, item) -> bool:
+    def _put(self, item, batch: int) -> bool:
         """Enqueue with stop-responsiveness; False if the consumer left."""
-        t0 = time.perf_counter()
-        while not self.stop.is_set():
-            try:
-                self.q.put(item, timeout=0.1)
-                t1 = time.perf_counter()
-                self.owner.stats.backpressure_sec += t1 - t0
-                if t1 - t0 >= obs.STALL_SPAN_MIN_SEC:
-                    # producer blocked on a full queue: the device is
-                    # the bottleneck for this interval
-                    obs.complete("ingest.backpressure", t0, t1, cat="ingest")
-                return True
-            except queue.Full:
-                continue
+        # producer blocked on a full queue: the device is the bottleneck
+        # for this interval
+        with obs.span("ingest.backpressure", obs.STALL_SPAN_MIN_SEC, batch=batch):
+            t0 = time.perf_counter()
+            while not self.stop.is_set():
+                try:
+                    self.q.put(item, timeout=0.1)
+                    self.owner.stats.backpressure_sec += time.perf_counter() - t0
+                    return True
+                except queue.Full:
+                    continue
         return False
 
     def _produce(self) -> None:
@@ -127,46 +136,45 @@ class _Pump:
         take_v6 = getattr(inner, "take_v6", None) if self._with_v6 else None
         cursor_rows = getattr(inner, "cursor_rows", None)
         pack = self._pack
+        seq = owner.stats.batches  # batch ids run on across the pumps
         try:
             while True:
-                t0 = time.perf_counter()
-                # fault sites: a producer bug (typed at the consumer) and
-                # a wedged producer (the consumer's stall watchdog fires)
-                faults.fire("ingest.producer.raise")
-                faults.fire("ingest.queue.stall", stop=self.stop)
-                nxt = next(self._it, None)
-                t_parsed = time.perf_counter()
+                with obs.span("ingest.produce", batch=seq):
+                    t0 = time.perf_counter()
+                    # fault sites: a producer bug (typed at the consumer)
+                    # and a wedged producer (the consumer's stall
+                    # watchdog fires)
+                    faults.fire("ingest.producer.raise")
+                    faults.fire("ingest.queue.stall", stop=self.stop)
+                    nxt = next(self._it, None)
+                    if nxt is not None:
+                        batch, n_raw = nxt
+                        # side effects of producing THIS batch, captured
+                        # now and committed only when the consumer
+                        # receives it
+                        v6 = take_v6() if take_v6 is not None else None
+                        parsed = inner.packer.parsed
+                        skipped = inner.packer.skipped
+                        cur = cursor_rows() if cursor_rows is not None else None
+                    t_parsed = time.perf_counter()
+                owner.stats.parse_sec += t_parsed - t0
                 if nxt is None:
                     break
-                batch, n_raw = nxt
-                # side effects of producing THIS batch, captured now and
-                # committed only when the consumer receives it
-                v6 = take_v6() if take_v6 is not None else None
-                parsed = inner.packer.parsed
-                skipped = inner.packer.skipped
-                cur = cursor_rows() if cursor_rows is not None else None
-                obs.complete(
-                    "ingest.produce", t0, t_parsed, cat="ingest",
-                    args={"n_raw": n_raw},
-                )
                 if pack is not None and batch is not None:
-                    batch = pack(batch)
                     # bit-pack + async sharded device_put (H2D issue)
-                    obs.complete(
-                        "ingest.pack", t_parsed, time.perf_counter(),
-                        cat="ingest",
-                    )
-                owner.stats.produce_sec += time.perf_counter() - t0
+                    with obs.span("ingest.pack", batch=seq):
+                        batch = pack(batch)
+                    owner.stats.pack_sec += time.perf_counter() - t_parsed
                 # t0 rides the item: the consumer records produce->commit
                 # latency into the batch-e2e histogram at receipt
-                if not self._put(
-                    ("item", (batch, n_raw, parsed, skipped, v6, cur, t0))
-                ):
+                item = ("item", (batch, n_raw, parsed, skipped, v6, cur, t0))
+                if not self._put(item, seq):
                     return
+                seq += 1
         except BaseException as e:  # re-raised typed at the consumer
-            self._put(("error", e))
+            self._put(("error", e), seq)
             return
-        self._put(_END)
+        self._put(_END, seq)
 
     def _get_bounded(self):
         """Next queue item, bounded by the stall watchdog.
@@ -202,14 +210,17 @@ class _Pump:
         self.thread.start()
         try:
             while True:
-                t0 = time.perf_counter()
-                tag, payload = self._get_bounded()
-                t1 = time.perf_counter()
+                # consumer blocked on an empty queue: the parse is the
+                # bottleneck for this interval.  FIFO from one producer,
+                # so the batch waited for is the next uncommitted one.
+                with obs.span(
+                    "ingest.starved", obs.STALL_SPAN_MIN_SEC,
+                    batch=owner.stats.batches,
+                ):
+                    t0 = time.perf_counter()
+                    tag, payload = self._get_bounded()
+                    t1 = time.perf_counter()
                 owner.stats.starved_sec += t1 - t0
-                if t1 - t0 >= obs.STALL_SPAN_MIN_SEC:
-                    # consumer blocked on an empty queue: the parse is
-                    # the bottleneck for this interval
-                    obs.complete("ingest.starved", t0, t1, cat="ingest")
                 if tag == "end":
                     return
                 if tag == "error":

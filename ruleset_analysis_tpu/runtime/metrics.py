@@ -1,8 +1,9 @@
-"""Observability meters: throughput, dispatch timing, recovery, profiling.
+"""Observability meters: throughput, dispatch spans, compile, recovery, profiling.
 
 The reference's only visibility is Hadoop's job counters and stdout
 (SURVEY.md §6).  Here: a periodic throughput line (lines/sec,
-instantaneous and cumulative), the compile-vs-sustained dispatch timer,
+instantaneous and cumulative), the ``step.dispatch`` span, the compile
+work JAX reports inside one call (:class:`CompileMeter`),
 recovery-event accounting, and an opt-in ``jax.profiler`` trace whose
 output loads in TensorBoard's profile plugin for per-op device timing.
 Every meter also feeds the unified tracing + metrics plane
@@ -202,9 +203,7 @@ class ThroughputMeter:
     (one None-check when ``--metrics-out`` is unset), and the periodic
     report line lands in the metrics JSONL as a ``throughput`` event in
     addition to stderr — a sustained run is watchable by tailing the
-    metrics file instead of scraping stderr.  :meth:`summary` folds the
-    final cumulative numbers into the report totals so downstream
-    artifacts stop re-deriving them.
+    metrics file instead of scraping stderr.
     """
 
     def __init__(self, report_every_chunks: int = 0, out=sys.stderr):
@@ -242,67 +241,85 @@ class ThroughputMeter:
     def elapsed(self) -> float:
         return time.perf_counter() - self.t0
 
-    def summary(self) -> dict:
-        """Final cumulative numbers for the report totals (``throughput``)."""
-        elapsed = self.elapsed()
-        return {
-            "chunks_ticked": self.chunks,
-            "lines": self.lines,
-            "elapsed_sec": round(elapsed, 4),
-            "lines_per_sec_cum": (
-                round(self.lines / elapsed, 1) if elapsed > 0 else 0.0
-            ),
-        }
+
+def dispatch(kind: str, fn, *args, batch: int | None = None):
+    """Run one device dispatch ``fn(*args)`` under a ``step.dispatch`` span.
+
+    Every device dispatch of the stream drivers and of serve goes
+    through here, so one span covers the whole step vocabulary.
+    ``batch`` is the source batch the step consumes: the producer's
+    ``ingest.produce``/``ingest.pack`` spans of that batch carry the
+    same id.
+    """
+    span_args = {"kind": kind} if batch is None else {"kind": kind, "batch": batch}
+    with obs.span("step.dispatch", **span_args):
+        return fn(*args)
 
 
-class DispatchTimer:
-    """Prices one-time jit/XLA-compile apart from the sustained rate.
+#: The ``jax.monitoring`` duration events one compile emits: trace to a
+#: jaxpr, lower to MLIR, and the backend compile — which wraps the
+#: persistent compile cache's read, so a cache hit is counted as an
+#: event but its seconds are not added a second time.
+COMPILE_EVENTS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+)
+CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
-    The first dispatch of each device program blocks on trace + compile;
-    its excess over the SECOND dispatch of the same program is the
-    one-time cost.  (On a backend with synchronous dispatch — XLA:CPU —
-    every dispatch also carries the chunk's execution, so
-    first-minus-second isolates compile where raw first-dispatch time
-    would launder one chunk's work into "compile".)  A program that
-    dispatched only ONCE contributes ZERO: its lone timing conflates
-    compile with a full chunk's execution, and subtracting it whole
-    from the sustained denominator would inflate the sustained rate by
-    10x+ on single-chunk runs — under-attributing compile there is the
-    conservative error.  Shared by the single-process and distributed
-    stream drivers so their ``totals.compile_sec`` mean the same thing.
+_compile_lock = threading.Lock()
+_compile_listening = False
+_compile_events = 0
+_compile_sec = 0.0
+_compile_fns: dict[str, float] = {}
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    global _compile_events, _compile_sec
+    timed = event in COMPILE_EVENTS
+    if not timed and event != CACHE_READ_EVENT:
+        return
+    with _compile_lock:
+        _compile_events += 1
+        if timed:
+            _compile_sec += duration
+            fn = str(kw.get("fun_name", "?"))
+            _compile_fns[fn] = _compile_fns.get(fn, 0.0) + duration
+
+
+class CompileMeter:
+    """The compile work JAX reports while one call runs.
+
+    One process-wide ``jax.monitoring`` listener, registered by the
+    first meter, counts the compile events of every thread; a meter is
+    the difference of those counts between its construction and
+    :meth:`summary`.  A warm call — every shape already compiled in this
+    process — reads zero events.
     """
 
     def __init__(self):
-        self._t: dict[str, list[float]] = {}
+        global _compile_listening
+        with _compile_lock:
+            if not _compile_listening:
+                import jax.monitoring
 
-    def first(self, kind: str, fn, *args):
-        """Run ``fn(*args)``, timing the first two dispatches of ``kind``.
+                jax.monitoring.register_event_duration_secs_listener(_on_duration)
+                _compile_listening = True
+            self._events, self._sec = _compile_events, _compile_sec
+            self._fns = dict(_compile_fns)
 
-        Every dispatch also records a ``step.dispatch`` trace span when
-        the observability plane is armed — this method already wraps
-        every device dispatch of both stream drivers, so one hook here
-        covers the whole step vocabulary.  Disarmed cost past the first
-        two dispatches: one None-check.
-        """
-        lst = self._t.setdefault(kind, [])
-        rec = obs.recording()  # tracer shard OR flight-recorder ring
-        if len(lst) >= 2 and not rec:
-            return fn(*args)
-        t0 = time.perf_counter()
-        out = fn(*args)
-        t1 = time.perf_counter()
-        if len(lst) < 2:
-            lst.append(t1 - t0)
-        if rec:
-            obs.complete(
-                "step.dispatch", t0, t1, cat="step", args={"kind": kind}
-            )
-        return out
-
-    def compile_sec(self) -> float:
-        return sum(
-            max(0.0, t[0] - t[1]) for t in self._t.values() if len(t) > 1
-        )
+    def summary(self) -> dict:
+        """``{"events", "sec", "fns"}``: ``fns`` maps each compiled
+        function's name to its seconds within this call."""
+        with _compile_lock:
+            events, sec = _compile_events, _compile_sec
+            fns = {
+                fn: round(s - self._fns.get(fn, 0.0), 4)
+                for fn, s in _compile_fns.items()
+                if s > self._fns.get(fn, 0.0)
+            }
+        return {"events": events - self._events,
+                "sec": round(sec - self._sec, 4), "fns": fns}
 
 
 class RecoveryMeter:

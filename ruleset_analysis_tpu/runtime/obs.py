@@ -14,6 +14,11 @@ verified by ``bench_suite.py obs``):
 
 - **Span tracer.**  :func:`complete`/:func:`span`/:func:`instant` record
   Chrome trace-event spans (loads in Perfetto / ``chrome://tracing``).
+  :func:`span` also opens a ``jax.profiler.TraceAnnotation`` in any
+  process that has imported JAX, so while a profiler session is open
+  (``run --profile-dir``, ``--devprof-out``, the benchmark's traced
+  runs) each span lands on the ``.xplane.pb``'s host plane, on the
+  profiler's clock, beside the device ops it issued or waited for.
   Each process appends to its own ``trace-<pid>.jsonl`` shard in the
   trace directory — newline-delimited complete events, flushed per
   event, so a worker that dies mid-run (even ``os._exit`` crash faults)
@@ -51,6 +56,7 @@ from __future__ import annotations
 import glob
 import json
 import os
+import sys
 import threading
 import time
 
@@ -62,8 +68,10 @@ ENV_VAR = "RA_TRACE_DIR"
 #: Default cadence of the metrics snapshotter (seconds).
 DEFAULT_METRICS_EVERY = 10.0
 
-#: Waits shorter than this never become backpressure/starved spans —
-#: a healthy pipeline's sub-millisecond queue handoffs are not stalls.
+#: Waits shorter than this never become backpressure/starved spans in
+#: the JSONL shards or the flight-recorder ring — a healthy pipeline's
+#: sub-millisecond queue handoffs are not stalls.  The profiler's host
+#: plane keeps them all (it has the device ops to line them up with).
 STALL_SPAN_MIN_SEC = 0.001
 
 #: Backstop age for pruning leftover shards whose writer PID appears
@@ -535,16 +543,6 @@ def note_role(role: str) -> None:
         fr.role = role
 
 
-def recording() -> bool:
-    """True when ANY event sink is live (tracer or flight-recorder ring).
-
-    The guard for call sites that measure endpoints themselves (e.g.
-    ``metrics.DispatchTimer``): they must keep timing when the always-on
-    black box is the only consumer.
-    """
-    return active_tracer() is not None or _flight is not None
-
-
 def complete(
     name: str, t0_pc: float, t1_pc: float, cat: str = "", args: dict | None = None
 ) -> None:
@@ -566,16 +564,6 @@ def instant(name: str, args: dict | None = None) -> None:
         fr.instant(name, args)
 
 
-def timed(name: str, fn, *args, **span_args):
-    """Run ``fn(*args)`` under a span; zero-wrapping when disarmed."""
-    if not recording():
-        return fn(*args)
-    t0 = time.perf_counter()
-    out = fn(*args)
-    complete(name, t0, time.perf_counter(), args=span_args or None)
-    return out
-
-
 class _NullSpan:
     __slots__ = ()
 
@@ -588,29 +576,65 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+#: ``jax.profiler.TraceAnnotation``, resolved on the first span of a
+#: process that has already imported JAX — never imported from here, so
+#: the JAX-free feeder worker processes stay JAX-free
+_annotation_cls = None
+
+
+def _annotation(name: str, args: dict):
+    """A profiler annotation for one span, or None in a JAX-free process.
+
+    Outside a profiler session it records nothing and costs well under a
+    microsecond; inside one it is a host-plane event on the profiler's
+    clock carrying ``args`` as its stats.
+    """
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        cls = _annotation_cls = TraceAnnotation
+    return cls(name, **args)
+
 
 class _Span:
-    __slots__ = ("_name", "_args", "_t0")
+    __slots__ = ("_name", "_args", "_min", "_ann", "_t0")
 
-    def __init__(self, name: str, args: dict | None):
-        self._name, self._args = name, args
+    def __init__(self, name: str, args: dict | None, min_sec: float, ann):
+        self._name, self._args, self._min, self._ann = name, args, min_sec, ann
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         # module-level complete() fans out to BOTH sinks (tracer shard
         # and flight-recorder ring), whichever subset is armed at exit
-        complete(self._name, self._t0, time.perf_counter(), args=self._args)
+        if t1 - self._t0 >= self._min:
+            complete(self._name, self._t0, t1, args=self._args)
         return False
 
 
-def span(name: str, **args):
-    """``with obs.span("stage.name"): ...`` — a shared no-op when disarmed."""
+def span(name: str, min_sec: float = 0.0, **args):
+    """``with obs.span("stage.name", key=value): ...``.
+
+    The span goes to the profiler's trace (when JAX is loaded) and to
+    the JSONL shard and flight-recorder ring (when armed, and when it
+    lasted at least ``min_sec``).  With neither it is the profiler
+    annotation alone, or a shared no-op in a JAX-free process.
+    """
+    ann = _annotation(name, args)
     if active_tracer() is None and _flight is None:
-        return _NULL_SPAN
-    return _Span(name, args or None)
+        return _NULL_SPAN if ann is None else ann
+    return _Span(name, args or None, min_sec, ann)
 
 
 # -- metrics module surface --------------------------------------------------

@@ -26,10 +26,11 @@ from ..hostside.pack import PackedRuleset
 #: so a new volatile block added to the runtime cannot silently break
 #: only SOME identity suites:
 #:
-#:   elapsed_sec/lines_per_sec/compile_sec/sustained_lines_per_sec —
+#:   elapsed_sec/lines_per_sec/sustained_lines_per_sec —
 #:       timings of this particular run
+#:   compile     trace/lower/compile work JAX reported inside the call
+#:   phases      the call's set-up and finalize seconds
 #:   ingest      pipeline overlap accounting (queue depths, waits)
-#:   throughput  the meter's cumulative split timings
 #:   coalesce    raw/unique compaction accounting (traffic-order shaped)
 #:   autoscale   scale decisions/timings (wall-clock, not answers)
 #:   recovery    elastic re-formation accounting
@@ -40,10 +41,10 @@ from ..hostside.pack import PackedRuleset
 VOLATILE_TOTALS = (
     "elapsed_sec",
     "lines_per_sec",
-    "compile_sec",
+    "compile",
+    "phases",
     "sustained_lines_per_sec",
     "ingest",
-    "throughput",
     "coalesce",
     "autoscale",
     "recovery",

@@ -67,6 +67,7 @@ from .metrics import (
     SloBurnEngine,
     SloPolicy,
     build_info,
+    dispatch,
     render_build_info_prom,
     window_slo_stats,
 )
@@ -1118,7 +1119,6 @@ class ServeDriver:
 
         from ..parallel import mesh as mesh_lib
         from ..parallel.step import make_parallel_step, make_parallel_step6
-        from .metrics import DispatchTimer
 
         scfg = self.scfg
         os.makedirs(scfg.serve_dir, exist_ok=True)
@@ -1193,7 +1193,6 @@ class ServeDriver:
             self._make_step6 = lambda p: make_parallel_step6(
                 self.mesh, self.cfg, p.n_keys
             )
-            self._dispatch = DispatchTimer()
             self._install_ruleset(self.packed)
             self._v6_digests: dict[int, int] = {}
             self._v6rows: list = []
@@ -1430,14 +1429,13 @@ class ServeDriver:
 
     def _kind(self, base: str) -> str:
         # per-world dispatch kinds: each scale rung compiles its own
-        # program, and the compile-vs-sustained split must price each
-        # geometry's first dispatches, not conflate them
+        # program, and its step.dispatch spans say which one ran
         return base if self._engine is None else f"{base}w{self.world}"
 
     def _run_chunk(self, batch_np: np.ndarray) -> None:
         wire = pack_mod.compact_batch(batch_np)
         dev = self._mesh_lib.shard_batch(self.mesh, wire, self.cfg.mesh_axis)
-        self.state, out = self._dispatch.first(
+        self.state, out = dispatch(
             self._kind("v4"), self.step, self.state, self.dev_rules, dev,
             self.n_chunks,
         )
@@ -1448,7 +1446,7 @@ class ServeDriver:
 
     def _run_chunk6(self, batch6_np: np.ndarray) -> None:
         dev = self._mesh_lib.shard_batch(self.mesh, batch6_np, self.cfg.mesh_axis)
-        self.state, out = self._dispatch.first(
+        self.state, out = dispatch(
             self._kind("v6"), self.step6, self.state, self.dev_rules6, dev,
             self.n_chunks,
         )

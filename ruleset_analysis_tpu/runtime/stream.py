@@ -29,6 +29,7 @@ from ..hostside.syslog import parse_line
 from ..models import pipeline
 from ..ops.topk import TopKTracker
 from . import devprof, faults, obs
+from .metrics import CompileMeter
 
 
 _SENTINEL = object()
@@ -298,16 +299,18 @@ def run_stream_packed(
     max_chunks: int | None = None,
 ):
     """Analyze pre-packed ``[TUPLE_COLS, n]`` tuple arrays (packed tier)."""
-    _arm_retry(cfg)
-    return _run_core(
-        packed,
-        _PackedSource(arrays),
-        cfg,
-        topk=topk,
-        mesh=mesh,
-        profile_dir=profile_dir,
-        max_chunks=max_chunks,
-    )
+    with _CallPhases() as phases:
+        _arm_retry(cfg)
+        return _run_core(
+            packed,
+            _PackedSource(arrays),
+            cfg,
+            topk=topk,
+            mesh=mesh,
+            profile_dir=profile_dir,
+            max_chunks=max_chunks,
+            phases=phases,
+        )
 
 
 class _WireFileSource:
@@ -524,18 +527,21 @@ def run_stream_wire(
     """
     if isinstance(paths, str):
         paths = [paths]
-    # arm BEFORE the source: the wire reader's open/header IO is itself
-    # a retry seam, and its attempts must land in THIS run's counters
-    _arm_retry(cfg)
-    return _run_core(
-        packed,
-        _WireFileSource(packed, paths),
-        cfg,
-        topk=topk,
-        mesh=mesh,
-        profile_dir=profile_dir,
-        max_chunks=max_chunks,
-    )
+    with _CallPhases() as phases:
+        # arm BEFORE the source: the wire reader's open/header IO is
+        # itself a retry seam, and its attempts must land in THIS run's
+        # counters
+        _arm_retry(cfg)
+        return _run_core(
+            packed,
+            _WireFileSource(packed, paths),
+            cfg,
+            topk=topk,
+            mesh=mesh,
+            profile_dir=profile_dir,
+            max_chunks=max_chunks,
+            phases=phases,
+        )
 
 
 def _needed_v6_digests(tracker, dig: dict[int, int]) -> dict[int, int]:
@@ -772,16 +778,18 @@ def run_stream(
     ``max_chunks`` stops after N chunks (fault-injection in tests; also a
     cheap "analyze a prefix" knob).
     """
-    _arm_retry(cfg)
-    return _run_core(
-        packed,
-        _TextSource(packed, lines),
-        cfg,
-        topk=topk,
-        mesh=mesh,
-        profile_dir=profile_dir,
-        max_chunks=max_chunks,
-    )
+    with _CallPhases() as phases:
+        _arm_retry(cfg)
+        return _run_core(
+            packed,
+            _TextSource(packed, lines),
+            cfg,
+            topk=topk,
+            mesh=mesh,
+            profile_dir=profile_dir,
+            max_chunks=max_chunks,
+            phases=phases,
+        )
 
 
 def run_stream_file(
@@ -844,37 +852,39 @@ def run_stream_file(
             "feed_mode='ring' needs feed_workers >= 1 (the per-chip "
             "producer pool size)"
         )
-    if feed_workers and (feed_workers > 1 or feed_mode == "ring"):
-        if native is False:
-            from ..errors import AnalysisError
+    with _CallPhases() as phases:
+        if feed_workers and (feed_workers > 1 or feed_mode == "ring"):
+            if native is False:
+                from ..errors import AnalysisError
 
-            raise AnalysisError(
-                "feed_workers requires the native parser; drop native=False"
+                raise AnalysisError(
+                    "feed_workers requires the native parser; drop native=False"
+                )
+            from ..hostside.feeder import ParallelFeeder, RingFeeder, ThreadedFeeder
+
+            feeder_cls = {
+                "thread": ThreadedFeeder,
+                "process": ParallelFeeder,
+                "ring": RingFeeder,
+            }[feed_mode]
+            source = feeder_cls(
+                packed, paths, n_workers=feed_workers,
+                stall_timeout=cfg.stall_timeout_sec,
             )
-        from ..hostside.feeder import ParallelFeeder, RingFeeder, ThreadedFeeder
-
-        feeder_cls = {
-            "thread": ThreadedFeeder,
-            "process": ParallelFeeder,
-            "ring": RingFeeder,
-        }[feed_mode]
-        source = feeder_cls(
-            packed, paths, n_workers=feed_workers,
-            stall_timeout=cfg.stall_timeout_sec,
+        elif use_native:
+            source = _FileSource(packed, paths)
+        else:
+            source = _TextSource(packed, _iter_files(paths))
+        return _run_core(
+            packed,
+            source,
+            cfg,
+            topk=topk,
+            mesh=mesh,
+            profile_dir=profile_dir,
+            max_chunks=max_chunks,
+            phases=phases,
         )
-    elif use_native:
-        source = _FileSource(packed, paths)
-    else:
-        source = _TextSource(packed, _iter_files(paths))
-    return _run_core(
-        packed,
-        source,
-        cfg,
-        topk=topk,
-        mesh=mesh,
-        profile_dir=profile_dir,
-        max_chunks=max_chunks,
-    )
 
 
 def run_stream_file_distributed(
@@ -1052,12 +1062,11 @@ def run_stream_file_distributed(
         packer = source.packer
         pending: deque[pipeline.ChunkOut] = deque()
 
-        # one-time jit/compile cost of each device program, priced apart
-        # from the sustained rate (shared discipline: metrics.DispatchTimer)
-        from .metrics import DispatchTimer
+        # compile work inside this call, priced apart from the sustained
+        # rate (the same meter as the single-process driver's)
+        from .metrics import dispatch
 
-        _dispatch = DispatchTimer()
-        _first_dispatch = _dispatch.first
+        compile_meter = CompileMeter()
 
         from . import checkpoint as ckpt
 
@@ -1243,7 +1252,7 @@ def run_stream_file_distributed(
                 )
             )
             gb = dist.to_global(mesh, b, P(None, data_ax))
-            state, out = _first_dispatch("v6", step6, state, rules6_g, gb, n_chunks)
+            state, out = dispatch("v6", step6, state, rules6_g, gb, n_chunks)
             pending.append(out)
             if len(pending) > 2:
                 drain(pending.popleft())
@@ -1446,7 +1455,7 @@ def run_stream_file_distributed(
                     else pack_mod.compact_grouped(grouped)
                 )
                 gbatch = dist.to_global(mesh, wire, P(None, None, data_ax))
-            state, out = _first_dispatch("v4", step, state, rules, gbatch, n_chunks)
+            state, out = dispatch("v4", step, state, rules, gbatch, n_chunks)
             pending.append(out)
             if len(pending) > 2:
                 drain(pending.popleft())
@@ -1475,7 +1484,7 @@ def run_stream_file_distributed(
                         else pack_mod.compact_batch(batch_np)
                     )
                     gbatch = dist.to_global(mesh, wire, P(None, data_ax))
-                state, out = _first_dispatch("v4", step, state, rules, gbatch, n_chunks)
+                state, out = dispatch("v4", step, state, rules, gbatch, n_chunks)
                 pending.append(out)
                 if len(pending) > 2:
                     drain(pending.popleft())
@@ -1536,7 +1545,7 @@ def run_stream_file_distributed(
                         dtype=np.uint32,
                     )
                 gb6 = dist.to_global(mesh, b6, P(None, data_ax))
-                state, out = _first_dispatch("v6", step6, state, rules6_g, gb6, n_chunks)
+                state, out = dispatch("v6", step6, state, rules6_g, gb6, n_chunks)
                 pending.append(out)
                 if len(pending) > 2:
                     drain(pending.popleft())
@@ -1579,23 +1588,20 @@ def run_stream_file_distributed(
             }
         )
         lines_this_run = agg.pop("lines_this_run")
-        compile_sec = _dispatch.compile_sec()
-        sustained = elapsed - compile_sec
+        compiled = compile_meter.summary()
+        sustained = elapsed - compiled["sec"]
         totals = {
             **agg,
             "chunks": n_chunks,
             "processes": nproc,
             "elapsed_sec": round(elapsed, 4),
             "lines_per_sec": round(lines_this_run / elapsed, 1) if elapsed > 0 else 0.0,
-            # one-time jit/XLA-compile cost (this process's first dispatch
-            # of each program), separated from the sustained rate
-            "compile_sec": round(compile_sec, 4),
+            # this process's compile work in the call, separated from
+            # the sustained rate
+            "compile": compiled,
             "sustained_lines_per_sec": (
                 round(lines_this_run / sustained, 1) if sustained > 0 else 0.0
             ),
-            # the meter's own cumulative numbers (THIS process's split),
-            # folded in so artifacts stop re-deriving them from stderr
-            "throughput": meter.summary(),
         }
         stats_fn = getattr(source, "ingest_stats", None)
         if stats_fn is not None:
@@ -1726,6 +1732,56 @@ def _dist_ckpt_layout_error(ckpt_dir: str, nproc: int) -> str | None:
     return None
 
 
+class _CallPhases:
+    """The call's own spans, and their seconds for ``totals.phases``.
+
+    ``run.call`` covers the whole driver call.  ``run.setup`` runs from
+    its entry to the first step dispatch: source construction, ruleset
+    ship, step lookup, state init, the producer's start and the wait for
+    the first batch.  ``run.finalize`` runs from after the last dispatch
+    to the built report: the state sync, the last candidate drains,
+    ``pipeline.finalize``.  All three are on the dispatching thread.
+    """
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        self.compile = CompileMeter()
+        self._call = obs.span("run.call")
+        self._call.__enter__()
+        self._setup = obs.span("run.setup")
+        self._setup.__enter__()
+        self._fin = None
+        self.setup_sec = 0.0
+        return self
+
+    def dispatching(self) -> None:
+        """Before each step dispatch: the first one ends set-up."""
+        if self._setup is not None:
+            self.setup_sec = time.perf_counter() - self._t0
+            self._setup.__exit__(None, None, None)
+            self._setup = None
+
+    def finalizing(self) -> None:
+        """After the last dispatch (a call with none ends set-up here)."""
+        self.dispatching()
+        self._t_fin = time.perf_counter()
+        self._fin = obs.span("run.finalize")
+        self._fin.__enter__()
+
+    def finalized(self) -> dict:
+        """The report is built: close ``run.finalize``; ``totals.phases``."""
+        sec = time.perf_counter() - self._t_fin
+        self._fin.__exit__(None, None, None)
+        self._fin = None
+        return {"setup_sec": round(self.setup_sec, 4), "finalize_sec": round(sec, 4)}
+
+    def __exit__(self, *exc):
+        for sp in (self._setup, self._fin, self._call):
+            if sp is not None:
+                sp.__exit__(*exc)
+        return False
+
+
 def _run_core(
     packed: PackedRuleset,
     source,
@@ -1735,6 +1791,7 @@ def _run_core(
     mesh,
     profile_dir: str | None,
     max_chunks: int | None,
+    phases: _CallPhases,
 ):
     """Run the chunk loop, deterministically closing the source after.
 
@@ -1836,6 +1893,7 @@ def _run_core(
             mesh=mesh,
             profile_dir=profile_dir,
             max_chunks=max_chunks,
+            phases=phases,
             device_ready=device_ready,
             coal=coal,
         )
@@ -1860,13 +1918,14 @@ def _run_core_impl(
     mesh,
     profile_dir: str | None,
     max_chunks: int | None,
+    phases: _CallPhases,
     device_ready: bool = False,
     coal=None,
 ):
     from ..parallel import mesh as mesh_lib
     from ..parallel.step import make_parallel_step
     from . import checkpoint as ckpt
-    from .metrics import Profiler, ThroughputMeter
+    from .metrics import Profiler, ThroughputMeter, dispatch
 
     # mesh is always resolved by _run_core (it needs it for the prefetch
     # pack closures) before this is called
@@ -1951,10 +2010,16 @@ def _run_core_impl(
         state = pipeline.init_state(packed.n_keys, cfg)
         tracker = TopKTracker(cfg.sketch.topk_capacity)
 
-    def drain(out: pipeline.ChunkOut) -> None:
-        tracker.offer_chunk(
-            np.asarray(out.cand_acl), np.asarray(out.cand_src), np.asarray(out.cand_est)
-        )
+    def drain(item: tuple[int, pipeline.ChunkOut]) -> None:
+        # the candidates of the chunk two dispatches back and their top-K
+        # offer; where the device sets the pace, the host waits for that
+        # chunk here (``step.fetch``)
+        bid, out = item
+        with obs.span("step.drain", batch=bid):
+            with obs.span("step.fetch", batch=bid):
+                cand = (np.asarray(out.cand_acl), np.asarray(out.cand_src),
+                        np.asarray(out.cand_est))
+            tracker.offer_chunk(*cand)
 
     def save_snapshot() -> None:
         nonlocal last_snap_chunks
@@ -1984,20 +2049,16 @@ def _run_core_impl(
             ),
         )
 
-    # One-time jit/compile + warmup priced SEPARATELY from the sustained
-    # rate (VERDICT r5 Weak #1; measurement discipline in DispatchTimer)
-    from .metrics import DispatchTimer
-
-    _dispatch = DispatchTimer()
-    _first_dispatch = _dispatch.first
-
     def run_chunk(batch_dev) -> None:
         # salt = chunk index: re-randomizes candidate-table slots per
         # chunk (no persistent talker collisions) yet replays exactly on
         # resume since n_chunks is restored from the snapshot
         nonlocal state, n_chunks
-        state, out = _first_dispatch("v4", step, state, dev_rules, batch_dev, n_chunks)
-        pending.append(out)
+        phases.dispatching()
+        state, out = dispatch(
+            "v4", step, state, dev_rules, batch_dev, n_chunks, batch=chunks_this_run
+        )
+        pending.append((chunks_this_run, out))
         if len(pending) > 2:
             drain(pending.popleft())
         n_chunks += 1
@@ -2026,11 +2087,12 @@ def _run_core_impl(
                 batch6_np = coal.tuple6(batch6_np)
             else:
                 batch6_np = coal.wire6(batch6_np)
-        state, out = _first_dispatch(
-            "v6", step6, state, dev_rules6,
-            mesh_lib.shard_batch(mesh, batch6_np, cfg.mesh_axis), n_chunks,
+        batch_dev = mesh_lib.shard_batch(mesh, batch6_np, cfg.mesh_axis)
+        phases.dispatching()
+        state, out = dispatch(
+            "v6", step6, state, dev_rules6, batch_dev, n_chunks, batch=chunks_this_run
         )
-        pending.append(out)
+        pending.append((chunks_this_run, out))
         if len(pending) > 2:
             drain(pending.popleft())
         n_chunks += 1
@@ -2075,7 +2137,7 @@ def _run_core_impl(
     # Candidates drain with a 2-chunk lag: by the time chunk N-2's arrays
     # are fetched, their compute is long done, so the host never stalls on
     # the device — and memory stays O(1) chunks instead of O(n_chunks).
-    pending: deque[pipeline.ChunkOut] = deque()
+    pending: deque[tuple[int, pipeline.ChunkOut]] = deque()  # (batch id, out)
     lines_at_start = lines_consumed  # nonzero after resume
     meter = ThroughputMeter(cfg.report_every_chunks)
     chunks_this_run = 0
@@ -2188,6 +2250,7 @@ def _run_core_impl(
 
     # every chunk has executed before elapsed() is read: the count
     # register's bytes cannot arrive earlier
+    phases.finalizing()
     pipeline.sync_state(state)
     elapsed = meter.elapsed()
     while pending:
@@ -2202,8 +2265,11 @@ def _run_core_impl(
     # both an in and an out ACL contributes two); lines_skipped counts
     # raw lines that produced no evaluation.
     lines_this_run = lines_consumed - lines_at_start
-    compile_sec = _dispatch.compile_sec()
-    sustained = elapsed - compile_sec
+    # trace + lower + XLA compile that JAX reported inside this call,
+    # priced separately: two committed e2e artifacts once disagreed 7.7x
+    # purely on how much of the run was compile
+    compiled = phases.compile.summary()
+    sustained = elapsed - compiled["sec"]
     totals = {
         "lines_total": lines_consumed,
         "lines_matched": packer.parsed,
@@ -2211,16 +2277,10 @@ def _run_core_impl(
         "chunks": n_chunks,
         "elapsed_sec": round(elapsed, 4),
         "lines_per_sec": round(lines_this_run / elapsed, 1) if elapsed > 0 else 0.0,
-        # one-time jit trace + XLA compile (first dispatch of each device
-        # program), priced separately: two committed e2e artifacts once
-        # disagreed 7.7x purely on how much of the run was compile
-        "compile_sec": round(compile_sec, 4),
+        "compile": compiled,
         "sustained_lines_per_sec": (
             round(lines_this_run / sustained, 1) if sustained > 0 else 0.0
         ),
-        # the meter's own cumulative numbers, folded into the report so
-        # downstream artifacts stop re-deriving them from stderr lines
-        "throughput": meter.summary(),
     }
     stats_fn = getattr(source, "ingest_stats", None)
     if stats_fn is not None:
@@ -2247,7 +2307,9 @@ def _run_core_impl(
         # wire input: restore the converter's raw-line accounting once the
         # whole file is consumed (rows != raw text lines)
         totals.update(patch(not aborted))
-    return pipeline.finalize(
+    rep = pipeline.finalize(
         state, packed, cfg, tracker, topk=topk, totals=totals,
         v6_digests=getattr(source, "v6_digests", None),
     )
+    rep.totals["phases"] = phases.finalized()
+    return rep

@@ -667,43 +667,47 @@ def test_serve_autoscale_e2e_burst_out_idle_in(serve_corpus, tmp_path):
     )
     drv = ServeDriver(prefix, cfg, scfg, topk=5, ascfg=a)
     th, out = _start(drv)
-    assert "error" not in out, out.get("error")
-    assert drv.world == 2  # starts at the configured initial rung
+    try:
+        assert "error" not in out, out.get("error")
+        assert drv.world == 2  # starts at the configured initial rung
 
-    # burst: the whole corpus at once — the device tier falls behind,
-    # queue occupancy (the pressure signal) sustains above threshold
-    s = socket.create_connection(drv.listeners.listeners[0].address)
-    s.sendall(("\n".join(lines) + "\n").encode())
-    s.close()
-    _wait(
-        lambda: any(d.direction == "out" for d in drv._engine.decisions),
-        90, "scale-out under burst",
-    )
-    # idle: the queue drains, starvation sustains, the mesh comes back
-    _wait(
-        lambda: any(d.direction == "in" for d in drv._engine.decisions),
-        120, "scale-in after the burst",
-    )
+        # burst: the whole corpus at once — the device tier falls behind,
+        # queue occupancy (the pressure signal) sustains above threshold
+        s = socket.create_connection(drv.listeners.listeners[0].address)
+        s.sendall(("\n".join(lines) + "\n").encode())
+        s.close()
+        _wait(
+            lambda: any(d.direction == "out" for d in drv._engine.decisions),
+            90, "scale-out under burst",
+        )
+        # idle: the queue drains, starvation sustains, the mesh comes back
+        _wait(
+            lambda: any(d.direction == "in" for d in drv._engine.decisions),
+            120, "scale-in after the burst",
+        )
 
-    # the one-source-of-truth metrics surface, Prometheus variant
-    host, port = drv.http_address
-    with urllib.request.urlopen(
-        f"http://{host}:{port}/metrics?format=prom", timeout=10
-    ) as r:
-        assert "version=0.0.4" in r.headers["Content-Type"]
-        prom = r.read().decode()
-    assert "ra_serve_queue_depth " in prom
-    assert "ra_serve_world " in prom
-    assert "ra_serve_autoscale_scale_out_total" in prom
-    with urllib.request.urlopen(
-        f"http://{host}:{port}/metrics", timeout=10
-    ) as r:
-        gauges = json.load(r)
-    assert gauges["world"] == drv.world  # JSON variant: same gauges
+        # the one-source-of-truth metrics surface, Prometheus variant
+        host, port = drv.http_address
+        with urllib.request.urlopen(
+            f"http://{host}:{port}/metrics?format=prom", timeout=10
+        ) as r:
+            assert "version=0.0.4" in r.headers["Content-Type"]
+            prom = r.read().decode()
+        assert "ra_serve_queue_depth " in prom
+        assert "ra_serve_world " in prom
+        assert "ra_serve_autoscale_scale_out_total" in prom
+        with urllib.request.urlopen(
+            f"http://{host}:{port}/metrics", timeout=10
+        ) as r:
+            gauges = json.load(r)
+        assert gauges["world"] == drv.world  # JSON variant: same gauges
 
-    _wait(lambda: drv.windows_published >= 3, 120, "3 windows")
-    drv.stop()
-    th.join(timeout=120)
+        _wait(lambda: drv.windows_published >= 3, 120, "3 windows")
+    finally:
+        # a failed wait must not leave the driver's listener and HTTP
+        # threads running into the tests after this one
+        drv.stop()
+        th.join(timeout=120)
     assert not th.is_alive(), "serve hung after stop"
     assert "error" not in out, out.get("error")
     summary = out["summary"]

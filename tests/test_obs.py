@@ -134,7 +134,6 @@ def test_disarmed_everything_is_noop(tmp_path):
     obs.register_sampler("x", lambda: {})
     with obs.span("x"):
         pass
-    assert obs.timed("x", lambda a: a + 1, 41) == 42
     assert obs.shutdown() is None
     assert not list(tmp_path.iterdir())
 
@@ -265,9 +264,8 @@ def test_throughput_meter_feeds_metrics_and_summary(tmp_path, capsys):
     for _ in range(4):
         meter.tick(100)
     obs.shutdown()
-    s = meter.summary()
-    assert s["lines"] == 400 and s["chunks_ticked"] == 4
-    assert s["lines_per_sec_cum"] > 0
+    assert meter.lines == 400 and meter.chunks == 4
+    assert meter.elapsed() > 0
     recs = _assert_metrics_well_formed(path)
     through = [r for r in recs if r["kind"] == "throughput"]
     assert len(through) == 2  # chunk 2 and chunk 4 report lines
@@ -368,9 +366,12 @@ def test_profiler_prints_tensorboard_hint_on_clean_exit(monkeypatch, tmp_path, c
 def test_report_totals_carry_throughput(corpus):
     packed, _prefix, log, _wirep = corpus
     rep = run_stream_file(packed, log, _cfg(), topk=5)
-    t = rep.totals["throughput"]
-    assert t["lines"] == rep.totals["lines_total"]
-    assert t["chunks_ticked"] >= 1 and t["elapsed_sec"] > 0
+    t = rep.totals
+    assert t["lines_total"] == 2400 and t["chunks"] >= 1
+    assert t["elapsed_sec"] > 0
+    assert t["lines_per_sec"] == pytest.approx(
+        t["lines_total"] / t["elapsed_sec"], rel=1e-2
+    )
 
 
 @pytest.mark.skipif(

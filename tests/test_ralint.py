@@ -373,7 +373,7 @@ def test_volatile_totals_imported_by_identity_suites():
     from ruleset_analysis_tpu.runtime.report import VOLATILE_TOTALS
 
     assert set(VOLATILE_TOTALS) >= {
-        "elapsed_sec", "lines_per_sec", "compile_sec",
-        "sustained_lines_per_sec", "ingest", "throughput", "coalesce",
+        "elapsed_sec", "lines_per_sec", "compile", "phases",
+        "sustained_lines_per_sec", "ingest", "coalesce",
         "autoscale", "recovery", "devprof",
     }
