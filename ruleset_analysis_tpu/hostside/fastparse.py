@@ -42,12 +42,15 @@ READ_BLOCK = 8 << 20
 def _as_buffer(data: bytes | bytearray | memoryview):
     """ctypes argument for a readable buffer, without copying.
 
-    bytes pass through (immutable, ctypes pins them); bytearray/memoryview
+    bytes pass through (immutable, ctypes pins them); a numpy array passes
+    its data pointer (the caller keeps it alive); bytearray/memoryview
     get a zero-copy ``from_buffer`` view — the caller must drop the
     returned object before resizing the underlying buffer.
     """
     if isinstance(data, bytes):
         return data
+    if isinstance(data, np.ndarray):
+        return data.ctypes.data
     return (ctypes.c_char * len(data)).from_buffer(data)
 
 
@@ -128,39 +131,28 @@ def _bind(lib: ctypes.CDLL) -> None:
         lib.asa_packer_skipped.argtypes = [ctypes.c_void_p]
         lib.asa_packer_skipped.restype = ctypes.c_int64
         lib.asa_packer_set_counts.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64]
-        # buf params are c_void_p (not c_char_p) so both immutable bytes
-        # and zero-copy views of a reusable bytearray can be passed
-        lib.asa_pack_chunk.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_void_p,
-            ctypes.c_int64,
-            ctypes.c_int,
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_uint32),
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_int64),
+        # buf params are c_void_p (not c_char_p) so immutable bytes and
+        # zero-copy views of a reusable buffer can both be passed
+        i64p = ctypes.POINTER(ctypes.c_int64)
+        u32p = ctypes.POINTER(ctypes.c_uint32)
+        lib.asa_pack_batch.argtypes = [
+            ctypes.c_void_p,  # packer
+            ctypes.c_void_p,  # buf
+            ctypes.c_int64,  # len
+            ctypes.c_int,  # final
+            ctypes.c_int64,  # max_lines
+            u32p,  # out [TUPLE_COLS, cap]
+            ctypes.c_int64,  # cap
+            u32p,  # out6 [TUPLE6_COLS, cap6], NULL for a v4-only ruleset
+            ctypes.c_int64,  # cap6
+            i64p,  # n_lines_out
+            i64p,  # n_valid_out
+            i64p,  # n_valid6_out
+            ctypes.c_int,  # n_threads
+            ctypes.c_int,  # full_batch
+            ctypes.POINTER(ctypes.c_int),  # workers_out
         ]
-        lib.asa_pack_chunk.restype = ctypes.c_int64
-        lib.asa_pack_chunk_mt.argtypes = lib.asa_pack_chunk.argtypes + [ctypes.c_int]
-        lib.asa_pack_chunk_mt.restype = ctypes.c_int64
-        # dual-family parse (v6-capable rulesets): v4 plane + TUPLE6 plane
-        lib.asa_pack_chunk2.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_void_p,
-            ctypes.c_int64,
-            ctypes.c_int,
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_uint32),
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_uint32),
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_int,
-        ]
-        lib.asa_pack_chunk2.restype = ctypes.c_int64
+        lib.asa_pack_batch.restype = ctypes.c_int64
         lib.asa_count_lines.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int, ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int64),
@@ -291,6 +283,10 @@ class NativePacker:
         #: and stage v6 rows for the driver's take_v6 side channel
         self._has_v6 = packed.has_v6
         self._staged6: list[np.ndarray] = []
+        #: workers the last batch parsed in parallel used (0: none yet),
+        #: and batches that took the one-thread loop
+        self.parse_workers = 0
+        self.parse_serial_batches = 0
 
     def take_v6(self):
         """Drain staged v6 rows as ONE ``[n, TUPLE6_COLS]`` uint32 array.
@@ -337,7 +333,8 @@ class NativePacker:
         n_threads: int | None = None,
         length: int | None = None,
         out: np.ndarray | None = None,
-    ) -> tuple[np.ndarray, int, int]:
+        full_batch: bool = False,
+    ) -> tuple[np.ndarray | None, int, int]:
         """Parse up to ``max_lines`` (default batch_size) lines from data.
 
         Returns (batch [TUPLE_COLS, batch_size] uint32, lines_consumed,
@@ -349,6 +346,9 @@ class NativePacker:
         (zero-copy prefix of a reusable buffer).  ``out`` supplies a
         preallocated ``[TUPLE_COLS, batch_size]`` uint32 C-contiguous
         destination (e.g. a shared-memory view) instead of a fresh array.
+        With ``full_batch`` and ``final=False``, data holding fewer than
+        ``max_lines`` complete lines is left unparsed: the call returns
+        ``(None, complete_lines_held, 0)`` so the caller can read more.
         """
         n = len(data) if length is None else length
         arg = _as_buffer(data)
@@ -364,51 +364,44 @@ class NativePacker:
                 raise ValueError("out must be C-contiguous")
         n_lines = ctypes.c_int64(0)
         n_valid = ctypes.c_int64(0)
+        n_valid6 = ctypes.c_int64(0)
+        workers = ctypes.c_int(0)
         ml = max_lines if max_lines is not None else batch_size
-        if self._has_v6:
-            # dual-family entry: the v6 plane is sized 2*max_lines so v6
-            # rows never close a batch, mirroring the Python text
-            # source's side buffer; parses across n_threads workers with
-            # bit-identical output (same slab/compaction structure as
-            # the v4 MT path)
-            cap6 = 2 * ml
-            out6 = np.empty((TUPLE6_COLS, cap6), dtype=np.uint32)
-            n_valid6 = ctypes.c_int64(0)
-            used = self._lib.asa_pack_chunk2(
+        u32p = ctypes.POINTER(ctypes.c_uint32)
+        # v6-capable ruleset: the v6 plane is sized 2*max_lines so v6 rows
+        # never close a batch, mirroring the Python text source's side
+        # buffer
+        cap6 = 2 * ml if self._has_v6 else 0
+        out6 = np.empty((TUPLE6_COLS, cap6), dtype=np.uint32) if cap6 else None
+        used = int(
+            self._lib.asa_pack_batch(
                 self._h,
                 arg,
                 n,
                 1 if final else 0,
                 ml,
-                out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
+                out.ctypes.data_as(u32p),
                 batch_size,
-                out6.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
+                out6.ctypes.data_as(u32p) if out6 is not None else None,
                 cap6,
                 ctypes.byref(n_lines),
                 ctypes.byref(n_valid),
                 ctypes.byref(n_valid6),
                 n_threads if n_threads is not None else default_parse_threads(),
+                1 if full_batch else 0,
+                ctypes.byref(workers),
             )
-            del arg
-            if int(n_valid6.value):
-                self._staged6.append(
-                    np.ascontiguousarray(out6[:, : int(n_valid6.value)].T)
-                )
-            return out, int(n_lines.value), int(used)
-        used = self._lib.asa_pack_chunk_mt(
-            self._h,
-            arg,
-            n,
-            1 if final else 0,
-            ml,
-            out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            batch_size,
-            ctypes.byref(n_lines),
-            ctypes.byref(n_valid),
-            n_threads if n_threads is not None else default_parse_threads(),
         )
         del arg  # release any buffer export before the caller resizes
-        return out, int(n_lines.value), int(used)
+        if used < 0:
+            return None, int(n_lines.value), 0
+        if workers.value > 1:
+            self.parse_workers = workers.value
+        else:
+            self.parse_serial_batches += 1
+        if out6 is not None and n_valid6.value:
+            self._staged6.append(np.ascontiguousarray(out6[:, : n_valid6.value].T))
+        return out, int(n_lines.value), used
 
     def _pack_lines_v4(self, lines: list[str], batch_size: int | None) -> np.ndarray:
         """The shared v4-plane pack (v6 rows, if any, land in _staged6)."""
@@ -520,55 +513,47 @@ def batches_from_files(
     lib = packer._lib
     reader = _ChainedReader(paths)
     try:
-        # Buffer management: one reusable bytearray filled with readinto —
-        # no per-block copies, no join.  (A naive ``rem += block`` chain
+        # Buffer management: one reusable buffer filled with readinto — no
+        # per-block copies, no join.  (A naive ``rem += block`` chain
         # re-copies the accumulated buffer per read — ~1.7 GB of memcpy
         # per 1M-line batch — and was measured to cost 4x end-to-end
-        # throughput.)  After each batch the unconsumed tail (at most
-        # ~read_block bytes) moves to the front.
-        buf = bytearray(2 * read_block)
+        # throughput.)  It is an uninitialised numpy array, grown only
+        # when a batch needs more bytes than it holds (once, after the
+        # first block shows the bytes per line), so no zero-fill runs on
+        # this thread.  After each batch the unconsumed tail (about
+        # read_block bytes) moves to the front.
+        buf = np.empty(2 * read_block, dtype=np.uint8)
         filled = 0  # bytes of buf holding live data
-        nl = 0  # newlines within buf[:filled]
         eof = False
 
-        def count_nl(start: int, end_: int) -> int:
-            if end_ <= start:
-                return 0
-            arr = (ctypes.c_char * (end_ - start)).from_buffer(buf, start)
-            try:
-                return int(lib.asa_count_nl(arr, end_ - start))
-            finally:
-                del arr
-
-        def fill() -> None:
-            nonlocal filled, nl, eof
-            if eof:
-                return
-            if len(buf) - filled < read_block:
-                buf.extend(bytes(len(buf)))  # grow geometrically
-            with memoryview(buf) as mv:
-                n = reader.readinto(mv[filled : filled + read_block])
-            if n == 0:
-                eof = True
-            else:
-                nl += count_nl(filled, filled + n)
-                filled += n
+        def fill(target: int) -> None:
+            """Read blocks until ``target`` bytes are held or input ends."""
+            nonlocal buf, filled, eof
+            while not eof and filled < target:
+                if len(buf) < filled + read_block:
+                    grown = np.empty(max(target, filled) + read_block, dtype=np.uint8)
+                    grown[:filled] = buf[:filled]
+                    buf = grown
+                n = reader.readinto(memoryview(buf)[filled : filled + read_block])
+                if n == 0:
+                    eof = True
+                else:
+                    filled += n
 
         def consume(used: int) -> None:
             """Drop buf[:used]; move the tail to the front."""
-            nonlocal filled, nl
+            nonlocal filled
             if used == 0:
                 return
             tail = filled - used
-            buf[0:tail] = buf[used:filled]
+            ctypes.memmove(buf.ctypes.data, buf.ctypes.data + used, tail)
             filled = tail
-            nl = count_nl(0, filled)
 
         # ---- resume fast-skip
         to_skip = skip_lines
         while to_skip > 0:
             if filled == 0 and not eof:
-                fill()
+                fill(1)
             if filled == 0 and eof:
                 from ..errors import ResumeInputMismatch
 
@@ -586,31 +571,40 @@ def batches_from_files(
             consume(int(bytes_used.value))
             if to_skip > 0 and int(skipped) == 0:
                 # newline-free fragment: grow the buffer to make progress
-                fill()
+                fill(filled + 1)
         # ---- stream batches
-        # Buffer until batch_size COMPLETE lines are held (not merely
-        # read_block bytes), then close each batch line-atomically: at
-        # most batch_size raw lines AND at most batch_size tuple rows —
-        # with out-direction bindings a dual-evaluation line can close a
-        # batch early, exactly as _TextSource does, so chunk boundaries —
-        # and therefore per-chunk top-K candidates and resume offsets —
-        # land exactly where the pure-Python text path puts them.
+        # Each batch holds batch_size COMPLETE lines (fewer only at the end
+        # of the input) and closes line-atomically: at most batch_size raw
+        # lines AND at most batch_size tuple rows — with out-direction
+        # bindings a dual-evaluation line can close a batch early, exactly
+        # as _TextSource does, so chunk boundaries — and therefore
+        # per-chunk top-K candidates and resume offsets — land exactly
+        # where the pure-Python text path puts them.  The native call
+        # counts the lines itself, in its workers, and says when the
+        # buffer holds too few; the reads are sized from the bytes per
+        # line seen so far, plus one block, so that is rare.
+        target = read_block
         while True:
-            while not eof and nl < batch_size:
-                fill()
+            fill(target)
             if filled == 0 and eof:
                 return
             batch, n_lines, used = packer.pack_chunk(
-                buf, batch_size, final=eof, length=filled
+                buf, batch_size, final=eof, length=filled, full_batch=True
             )
-            consume(used)
-            if n_lines == 0:
-                if eof:
-                    return
-                # no complete line yet (line longer than the buffered
-                # bytes): force another read so we always make progress
-                fill()
+            if batch is None:
+                # fewer than batch_size complete lines held (a line may be
+                # longer than the buffered bytes): read one block more than
+                # the missing lines take at the bytes per line held, and at
+                # most 32 times what is held, so a few long lines cannot
+                # size the buffer for a whole batch of them
+                missing = batch_size - n_lines
+                more = missing * filled // n_lines if n_lines else 0
+                target = filled + read_block + min(more, 32 * filled)
                 continue
+            consume(used)
+            if n_lines == 0:  # nothing left at the end of the input
+                return
+            target = used * batch_size // n_lines + read_block
             yield batch, n_lines
     finally:
         reader.close()

@@ -27,6 +27,7 @@
 // C ABI only (loaded via ctypes; no pybind11 in this image).
 
 #include <atomic>
+#include <condition_variable>
 #include <cstddef>
 #include <cstdint>
 #include <cstdlib>
@@ -84,8 +85,11 @@ namespace {
 using ra_parse::HandleLineFn;
 using ra_parse::LocalCtx;
 using ra_parse::Packer;
+using ra_parse::RawBuf;
+using ra_parse::WorkerScratch;
 
 constexpr int64_t TUPLE_COLS = 7;
+constexpr int64_t TUPLE6_COLS = 13;
 
 // ---------------------------------------------------------------------------
 // Runtime SIMD dispatch: ONE handle-line pointer (whole-line parser,
@@ -130,39 +134,313 @@ inline const ra_simd::ScanOps* scan_ops() {
     return g_scan_ops.load(std::memory_order_relaxed);
 }
 
-// Build the line-start index for the MT parse paths: up to ``want``
-// complete lines from [buf, buf+len), plus the trailing unterminated
-// fragment as a final line when ``final_``.  Pushes each line's start
-// offset onto ``off`` and returns one past the consumed region.  The
-// SIMD path gathers every newline position in bulk (32 bytes/cycle of
-// classify+movemask) instead of one memchr call per line.
-const char* build_line_index(const char* buf, int64_t len, int final_,
-                             int64_t want, std::vector<uint32_t>& off) {
+// Zero rows [from, to) of every column of a column-major [cols, cap]
+// plane.  Callers allocate the output uninitialised (np.empty); the
+// contract is "padding rows are all-zero", matching the pure-Python
+// LinePacker while writing only the padding.
+void zero_rows(uint32_t* out, int64_t cols, int64_t cap, int64_t from,
+               int64_t to) {
+    if (to <= from) return;
+    for (int64_t c = 0; c < cols; ++c)
+        memset(out + c * cap + from, 0, (size_t)(to - from) * sizeof(uint32_t));
+}
+
+// Barrier over the workers of one call.  The last thread to arrive runs
+// `serial` (the cut and the prefix sums between two passes) before any
+// thread is released, so every worker sees its results.
+class Barrier {
+  public:
+    explicit Barrier(int n) : n_(n) {}
+
+    template <class F>
+    void arrive_and_wait(F&& serial) {
+        std::unique_lock<std::mutex> lk(m_);
+        const uint64_t gen = gen_;
+        if (++waiting_ == n_) {
+            serial();
+            waiting_ = 0;
+            ++gen_;
+            cv_.notify_all();
+            return;
+        }
+        cv_.wait(lk, [&] { return gen_ != gen; });
+    }
+
+  private:
+    std::mutex m_;
+    std::condition_variable cv_;
+    const int n_;
+    int waiting_ = 0;
+    uint64_t gen_ = 0;
+};
+
+// Offsets from p of every '\n' in [p, p + n), into the grow-only `nl`;
+// returns how many.  The SIMD path gathers positions 32 bytes at a time
+// (classify + movemask) instead of one memchr call per line.
+int64_t scan_newlines(const char* p, int64_t n, RawBuf<uint32_t>& nl) {
+    const ra_simd::ScanOps* ops = scan_ops();
+    uint32_t* out = nl.reserve((size_t)(n / 64 + 1024));
+    int64_t c = 0, from = 0;  // found so far; where the scan resumes
+    for (;;) {
+        const int64_t room = (int64_t)nl.n - c;
+        int64_t got = 0;
+        if (ops) {
+            got = ops->nl_positions(p + from, n - from, out + c, room);
+        } else {
+            const char* q = p + from;
+            const char* end = p + n;
+            while (got < room &&
+                   (q = (const char*)memchr(q, '\n', end - q)) != nullptr) {
+                out[c + got++] = (uint32_t)(q - (p + from));
+                ++q;
+            }
+        }
+        if (from)
+            for (int64_t i = c; i < c + got; ++i) out[i] += (uint32_t)from;
+        c += got;
+        if (got < room) return c;
+        // full: grow, and resume one past the last newline found
+        from = (int64_t)out[c - 1] + 1;
+        out = nl.reserve(2 * nl.n, (size_t)c);
+    }
+}
+
+// The one-thread streaming loop: the reference semantics, and the path
+// for a single worker.  Batches are line-atomic: when a line's rows (up
+// to two: in- and out-evaluation) don't fit, it stays unconsumed and
+// opens the next batch, exactly like the Python _TextSource.
+int64_t pack_serial(Packer* pk, HandleLineFn handle, const char* buf,
+                    int64_t len, int final_, int full_batch, int64_t max_lines,
+                    uint32_t* out, int64_t cap, uint32_t* out6, int64_t cap6,
+                    int64_t* n_lines_out, int64_t* n_valid_out,
+                    int64_t* n_valid6_out) {
+    LocalCtx cx{&pk->resolve, {}};
     const char* end = buf + len;
     const char* p = buf;
-    const ra_simd::ScanOps* ops = scan_ops();
-    if (ops && want > 0) {
-        std::vector<uint32_t> nls((size_t)want);
-        int64_t c = ops->nl_positions(buf, len, nls.data(), want);
-        uint32_t start = 0;
-        for (int64_t i = 0; i < c; ++i) {
-            off.push_back(start);
-            start = nls[(size_t)i] + 1;
-        }
-        p = buf + start;
-        if (c < want && p < end && final_) {  // trailing fragment
-            off.push_back(start);
-            p = end;
-        }
-        return p;
-    }
-    while (p < end && (int64_t)off.size() < want) {
+    int64_t lines = 0, valid = 0, valid6 = 0, parsed = 0, skipped = 0;
+    bool closed = false;  // a line's rows did not fit
+    while (p < end && lines < max_lines) {
         const char* nl = (const char*)memchr(p, '\n', end - p);
         if (!nl && !final_) break;  // incomplete tail line
-        off.push_back((uint32_t)(p - buf));
+        const char* le = nl ? nl : end;
+        const int64_t v6_before = valid6;
+        int n = handle(&cx, p, le, out, cap, valid, out6, cap6,
+                       out6 ? &valid6 : nullptr);
+        if (n < 0) {
+            closed = true;
+            break;
+        }
+        if (n == 0) ++skipped;
+        else {
+            parsed += n;
+            if (valid6 == v6_before) valid += n;  // v4 rows advanced
+        }
+        ++lines;
         p = nl ? nl + 1 : end;
     }
-    return p;
+    if (full_batch && !final_ && !closed && lines < max_lines) {
+        *n_lines_out = lines;  // fewer than a batch of complete lines
+        return -1;
+    }
+    pk->parsed += parsed;
+    pk->skipped += skipped;
+    zero_rows(out, TUPLE_COLS, cap, valid, cap);
+    if (out6) zero_rows(out6, TUPLE6_COLS, cap6, valid6, cap6);
+    *n_lines_out = lines;
+    *n_valid_out = valid;
+    *n_valid6_out = valid6;
+    return p - buf;
+}
+
+// One worker's share of one parallel call.
+struct Part {
+    int64_t b0 = 0, b1 = 0;  // its byte range of the buffer
+    int64_t n_nl = 0;        // newlines in that range
+    int64_t g0 = 0;          // global index of the first of them
+    int64_t l0 = 0, l1 = 0;  // the lines it parses
+    int64_t slab_cap = 0;    // rows its slabs hold: 2 per line
+    int64_t rows4 = 0, rows6 = 0, skip = 0;  // its lines' rows; lines with none
+    int64_t take = 0, take4 = 0, take6 = 0;  // of those, what the batch consumes
+    int64_t at4 = 0, at6 = 0;                // where its rows land in out, out6
+};
+
+// The parallel pass: every byte-proportional step runs in the W workers.
+//   1. Each worker scans its own byte range for newlines.  A prefix sum
+//      over the W counts places the cut after line `want` (fewer at the
+//      end of the input) and gives each worker an even share of lines.
+//   2. Each worker parses its lines into its slabs (kept by the Packer,
+//      never zero-filled) with a thread-local context, recording each
+//      line's row count.  The line-atomic row cap then fixes K, the lines
+//      consumed, exactly as the one-thread loop would, and a prefix sum
+//      gives each worker's row offset in out.
+//   3. Each worker copies its consumed rows into out at that offset and
+//      zeroes its share of the padding rows.
+int64_t pack_parallel(Packer* pk, HandleLineFn handle, const char* buf,
+                      int64_t len, int final_, int full_batch, int64_t want,
+                      int W, uint32_t* out, int64_t cap, uint32_t* out6,
+                      int64_t cap6, int64_t* n_lines_out,
+                      int64_t* n_valid_out, int64_t* n_valid6_out) {
+    if ((int)pk->scratch.size() < W) pk->scratch.resize((size_t)W);
+    std::vector<WorkerScratch>& scratch = pk->scratch;
+    std::vector<Part> part((size_t)W);
+    for (int w = 0; w < W; ++w) {
+        part[w].b0 = len * w / W;
+        part[w].b1 = len * (w + 1) / W;
+    }
+    // byte offset one past global newline i
+    auto nl_end = [&](int64_t i) -> int64_t {
+        int w = 0;
+        while (i >= part[w].g0 + part[w].n_nl) ++w;
+        return part[w].b0 + scratch[w].nl.p[i - part[w].g0] + 1;
+    };
+    int64_t N = 0;        // complete lines in the buffer
+    int64_t L = 0;        // lines parsed: the batch and at most the fragment
+    int64_t end_L = 0;    // one past line L - 1
+    int64_t K = 0, valid = 0, valid6 = 0, parsed = 0, skipped = 0, used = 0;
+    bool is_short = false;
+    Barrier bar(W);
+
+    auto work = [&](int w) {
+        Part& P = part[w];
+        WorkerScratch& S = scratch[w];
+        // ---- pass 1: newlines of this worker's byte range; the cut
+        P.n_nl = scan_newlines(buf + P.b0, P.b1 - P.b0, S.nl);
+        bar.arrive_and_wait([&] {
+            for (int v = 0; v < W; ++v) {
+                part[v].g0 = N;
+                N += part[v].n_nl;
+            }
+            if (N >= want) {
+                L = want;
+                end_L = want ? nl_end(want - 1) : 0;
+            } else if (final_) {  // the unterminated fragment is a line
+                L = N + ((N ? nl_end(N - 1) : 0) < len ? 1 : 0);
+                end_L = len;
+            } else if (full_batch) {
+                is_short = true;
+            } else {
+                L = N;
+                end_L = N ? nl_end(N - 1) : 0;
+            }
+            for (int v = 0; v < W; ++v) {
+                part[v].l0 = L * v / W;
+                part[v].l1 = L * (v + 1) / W;
+            }
+        });
+        if (is_short) return;
+
+        // ---- pass 2: parse lines [l0, l1) into this worker's slabs
+        const int64_t n = P.l1 - P.l0;
+        P.slab_cap = 2 * n;
+        uint32_t* slab4 = S.slab4.reserve((size_t)(TUPLE_COLS * P.slab_cap));
+        uint32_t* slab6 =
+            out6 ? S.slab6.reserve((size_t)(TUPLE6_COLS * P.slab_cap)) : nullptr;
+        uint8_t* r4 = S.rows4.reserve((size_t)n);
+        uint8_t* r6 = out6 ? S.rows6.reserve((size_t)n) : nullptr;
+        if (n > 0) {
+            // line l0 starts one past newline l0 - 1; each line ends at the
+            // next newline, found by walking the workers' newline lists
+            int v = 0;
+            while (v < W - 1 && P.l0 >= part[v].g0 + part[v].n_nl) ++v;
+            int64_t j = P.l0 - part[v].g0;
+            const char* ls = buf + (P.l0 ? nl_end(P.l0 - 1) : 0);
+            LocalCtx cx{&pk->resolve, {}};
+            int64_t v4 = 0, v6 = 0, skip = 0;
+            for (int64_t i = 0; i < n; ++i) {
+                const char* le = buf + len;  // the unterminated fragment
+                if (P.l0 + i < N) {
+                    while (j == part[v].n_nl) {
+                        ++v;
+                        j = 0;
+                    }
+                    le = buf + part[v].b0 + scratch[v].nl.p[j++];
+                }
+                const int64_t v6_before = v6;
+                int r = handle(&cx, ls, le, slab4, P.slab_cap, v4, slab6,
+                               P.slab_cap, out6 ? &v6 : nullptr);
+                // r < 0 impossible: slab caps are 2 * the worker's lines
+                if (r > 0 && v6 != v6_before) {
+                    r4[i] = 0;
+                    r6[i] = (uint8_t)r;
+                } else {
+                    r4[i] = (uint8_t)(r > 0 ? r : 0);
+                    if (r6) r6[i] = 0;
+                    v4 += r4[i];
+                    if (r <= 0) ++skip;
+                }
+                ls = le + 1;
+            }
+            P.rows4 = v4;
+            P.rows6 = v6;
+            P.skip = skip;
+        }
+        bar.arrive_and_wait([&] {
+            // the line-atomic row cap on the v4 plane: K maximal with the
+            // rows of lines 0..K-1 fitting in cap (cap6 >= 2 * max_lines by
+            // the caller's contract, so v6 rows never close a batch)
+            bool closed = false;
+            for (int v = 0; v < W; ++v) {
+                Part& Q = part[v];
+                Q.at4 = valid;
+                Q.at6 = valid6;
+                if (closed) continue;
+                if (valid + Q.rows4 <= cap) {
+                    Q.take = Q.l1 - Q.l0;
+                    Q.take4 = Q.rows4;
+                    Q.take6 = Q.rows6;
+                    skipped += Q.skip;
+                } else {  // the first line that does not fit is in here
+                    const uint8_t* q4 = scratch[v].rows4.p.get();
+                    const uint8_t* q6 = out6 ? scratch[v].rows6.p.get() : nullptr;
+                    int64_t i = 0;
+                    for (; valid + Q.take4 + q4[i] <= cap; ++i) {
+                        const int64_t rr6 = q6 ? q6[i] : 0;
+                        Q.take4 += q4[i];
+                        Q.take6 += rr6;
+                        if (q4[i] == 0 && rr6 == 0) ++skipped;
+                    }
+                    Q.take = i;
+                    closed = true;
+                }
+                K += Q.take;
+                valid += Q.take4;
+                valid6 += Q.take6;
+                parsed += Q.take4 + Q.take6;
+            }
+            used = K < L ? (K ? nl_end(K - 1) : 0) : end_L;
+        });
+
+        // ---- pass 3: consumed rows into out; this worker's padding share
+        if (P.take4)
+            for (int64_t c = 0; c < TUPLE_COLS; ++c)
+                memcpy(out + c * cap + P.at4, slab4 + c * P.slab_cap,
+                       (size_t)P.take4 * sizeof(uint32_t));
+        if (P.take6)
+            for (int64_t c = 0; c < TUPLE6_COLS; ++c)
+                memcpy(out6 + c * cap6 + P.at6, slab6 + c * P.slab_cap,
+                       (size_t)P.take6 * sizeof(uint32_t));
+        zero_rows(out, TUPLE_COLS, cap, valid + (cap - valid) * w / W,
+                  valid + (cap - valid) * (w + 1) / W);
+        if (out6)
+            zero_rows(out6, TUPLE6_COLS, cap6, valid6 + (cap6 - valid6) * w / W,
+                      valid6 + (cap6 - valid6) * (w + 1) / W);
+    };
+
+    std::vector<std::thread> threads;
+    threads.reserve((size_t)(W - 1));
+    for (int w = 1; w < W; ++w) threads.emplace_back(work, w);
+    work(0);
+    for (auto& t : threads) t.join();
+    if (is_short) {
+        *n_lines_out = N;
+        return -1;
+    }
+    pk->parsed += parsed;
+    pk->skipped += skipped;
+    *n_lines_out = K;
+    *n_valid_out = valid;
+    *n_valid6_out = valid6;
+    return used;
 }
 
 }  // namespace
@@ -208,334 +486,46 @@ void asa_packer_set_counts(void* h, int64_t parsed, int64_t skipped) {
     ((Packer*)h)->skipped = skipped;
 }
 
-// Zero the padding rows [valid, cap) of every column.  Callers allocate
-// the output uninitialized (np.empty); the contract is "padding rows are
-// all-zero", matching the pure-Python LinePacker exactly while memsetting
-// only the (usually small) tail instead of the whole 28 MB buffer.
-void zero_tail(uint32_t* out, int64_t cap, int64_t valid) {
-    for (int64_t c = 0; c < TUPLE_COLS; ++c)
-        memset(out + c * cap + valid, 0, (size_t)(cap - valid) * sizeof(uint32_t));
-}
-
-// Parse up to max_lines newline-terminated lines from buf[0:len) into the
-// column-major uint32 out[TUPLE_COLS][cap], using up to n_threads parse
-// workers over contiguous line ranges.  With final==0 a trailing fragment
-// without '\n' is left unconsumed; with final!=0 it is parsed as the last
-// line.  Returns bytes consumed; *n_lines_out lines were consumed,
-// *n_valid_out tuples written (rows 0..n_valid-1; rows beyond are zero).
+// Parse up to max_lines lines of buf[0:len) into the column-major
+// out[TUPLE_COLS][cap] (v4 rows) and, when out6 is given, out6[13][cap6]
+// (v6 rows, limb layout, pack.py; callers size cap6 >= 2 * max_lines so
+// the v6 side never closes a batch, as the Python _TextSource's side
+// buffer never does).  With final==0 a trailing fragment without '\n' is
+// left unconsumed; with final!=0 it is parsed as the last line.  Returns
+// bytes consumed; *n_lines_out lines were consumed, *n_valid_out and
+// *n_valid6_out rows written (rows beyond are zero).
 //
-// Parallel structure (SURVEY.md §2 L2 — the input-split analog): one
-// newline-scan pass builds the line-offset index; lines split evenly
-// across workers; each worker parses its range into a private
-// column-major slab with a thread-local context; a sequential compaction
-// then concatenates the slabs' valid rows in range order.  The output —
-// tuple order, counts, consumed bytes — is bit-identical to the
-// single-threaded parse.
-int64_t asa_pack_chunk_mt(void* h, const char* buf, int64_t len, int final_,
-                          int64_t max_lines, uint32_t* out, int64_t cap,
-                          int64_t* n_lines_out, int64_t* n_valid_out,
-                          int n_threads) {
-    simd_init();
-    Packer* pk = (Packer*)h;
-    const char* end = buf + len;
-    int64_t want = max_lines < cap ? max_lines : cap;
-    const HandleLineFn handle = handle_line_fn();
-
-    // the parallel path indexes lines with uint32 offsets, and its
-    // even-line split can't honor the "keep consuming raw lines while
-    // valid < cap" contract that binds when max_lines > cap — route both
-    // cases through the exact sequential loop
-    if (n_threads != 1 && (len > (int64_t)0xFFFFFFFF || max_lines > cap))
-        n_threads = 1;
-
-    if (n_threads == 1) {
-        // direct streaming loop: no line index, no scratch — the
-        // fastest path for one core and the reference semantics for the
-        // parity tests.  Batches are line-atomic: when a line's rows
-        // (up to two — in + out evaluation) don't fit, it stays
-        // unconsumed and opens the next batch, exactly like the Python
-        // _TextSource.
-        LocalCtx cx{&pk->resolve, {}};
-        const char* p = buf;
-        int64_t lines = 0, valid = 0;
-        int64_t parsed = 0, skipped = 0;
-        while (p < end && lines < max_lines) {
-            const char* nl = (const char*)memchr(p, '\n', end - p);
-            const char* le = nl ? nl : end;
-            if (!nl && !final_) break;  // incomplete tail line
-            int n = handle(&cx, p, le, out, cap, valid, nullptr, 0, nullptr);
-            if (n < 0) break;  // rows don't fit: close batch, keep line
-            if (n == 0) ++skipped;
-            else { valid += n; parsed += n; }
-            ++lines;
-            p = nl ? nl + 1 : end;
-        }
-        pk->parsed += parsed;
-        pk->skipped += skipped;
-        zero_tail(out, cap, valid);
-        *n_lines_out = lines;
-        *n_valid_out = valid;
-        return p - buf;
-    }
-
-    // ---- pass 1: line-offset index (off[i] = start of line i; off[L] =
-    // one past the consumed region)
-    std::vector<uint32_t> off;
-    off.reserve((size_t)(want > 0 ? want + 1 : 1));
-    const char* p = build_line_index(buf, len, final_, want, off);
-    const int64_t L = (int64_t)off.size();
-    if (L == 0) {
-        zero_tail(out, cap, 0);  // same "padding rows are zero" contract
-        *n_lines_out = 0;
-        *n_valid_out = 0;
-        return 0;
-    }
-    const int64_t consumed = p - buf;
-    off.push_back((uint32_t)consumed);
-    // line i spans [buf+off[i], buf+off[i+1]) minus the trailing '\n'
-    auto line_end = [&](int64_t i) {
-        const char* q = buf + off[i + 1];
-        return (q > buf + off[i] && q[-1] == '\n') ? q - 1 : q;
-    };
-
-    int W = n_threads;
-    if (W <= 0) W = (int)std::thread::hardware_concurrency();
-    if (W < 1) W = 1;
-    if (W > (int)(L / 1024) + 1) W = (int)(L / 1024) + 1;  // tiny batches: few
-
-    // ---- workers: private slabs (2 rows per line: a connection line can
-    // emit both an in- and an out-evaluation), thread-local contexts.
-    // rows_per_line records each line's emission count so the compaction
-    // can re-apply the line-atomic row cap exactly as the sequential loop
-    // (and the Python _TextSource) would.
-    std::vector<uint32_t> scratch((size_t)(TUPLE_COLS * 2 * L));
-    std::vector<uint8_t> rows_per_line((size_t)L);
-    std::vector<int64_t> lo(W + 1);
-    for (int w = 0; w <= W; ++w) lo[w] = L * w / W;
-    std::vector<LocalCtx> ctx((size_t)W);
-    std::vector<std::thread> threads;
-    threads.reserve((size_t)W);
-    for (int w = 0; w < W; ++w) {
-        ctx[w].resolve = &pk->resolve;
-        threads.emplace_back([&, w]() {
-            const int64_t i0 = lo[w], i1 = lo[w + 1];
-            const int64_t slab_cap = 2 * (i1 - i0);
-            uint32_t* slab = scratch.data() + (size_t)(2 * i0 * TUPLE_COLS);
-            LocalCtx* cx = &ctx[w];
-            int64_t v = 0;
-            for (int64_t i = i0; i < i1; ++i) {
-                int n = handle(cx, buf + off[i], line_end(i), slab, slab_cap,
-                               v, nullptr, 0, nullptr);
-                // n < 0 impossible: slab_cap == 2 * range lines
-                rows_per_line[(size_t)i] = (uint8_t)(n > 0 ? n : 0);
-                if (n > 0) v += n;
-            }
-        });
-    }
-    for (auto& t : threads) t.join();
-
-    // ---- line-atomic row cap: consume lines 0..K-1, K maximal with the
-    // cumulative rows fitting in cap (the first non-fitting valid line
-    // closes the batch, exactly like the sequential loop)
-    int64_t K = 0, total_rows = 0;
-    int64_t parsed = 0, skipped = 0;
-    for (; K < L; ++K) {
-        const int64_t r = rows_per_line[(size_t)K];
-        if (total_rows + r > cap) break;
-        total_rows += r;
-        if (r == 0) ++skipped; else parsed += r;
-    }
-
-    // ---- compaction: concatenate consumed lines' rows, preserving order
-    int64_t valid = 0;
-    for (int w = 0; w < W && lo[w] < K; ++w) {
-        const int64_t i0 = lo[w], i1 = lo[w + 1] < K ? lo[w + 1] : K;
-        const int64_t slab_cap = 2 * (lo[w + 1] - i0);
-        const uint32_t* slab = scratch.data() + (size_t)(2 * i0 * TUPLE_COLS);
-        int64_t take = 0;  // rows of this worker's consumed lines
-        for (int64_t i = i0; i < i1; ++i) take += rows_per_line[(size_t)i];
-        for (int64_t c = 0; c < TUPLE_COLS; ++c)
-            memcpy(out + c * cap + valid, slab + c * slab_cap,
-                   (size_t)take * sizeof(uint32_t));
-        valid += take;
-    }
-    pk->parsed += parsed;
-    pk->skipped += skipped;
-    zero_tail(out, cap, valid);
-    *n_lines_out = K;
-    *n_valid_out = valid;
-    return K < L ? (int64_t)off[K] : consumed;
-}
-
-// Single-threaded ABI kept for compatibility.
-int64_t asa_pack_chunk(void* h, const char* buf, int64_t len, int final_,
+// With full_batch!=0 and final==0, a buffer holding fewer than max_lines
+// complete lines is left as it is: the call returns -1 with
+// *n_lines_out = the complete lines it holds, so the caller can read
+// more and call again.
+//
+// Up to n_threads workers (<= 0: one per core), at least 1024 lines of a
+// batch each, run the one parallel pass above; one worker, buffers over
+// 4 GiB (uint32 offsets) and max_lines > cap (the cut cannot keep taking
+// lines while rows fit) run the one-thread loop.  *workers_out says how
+// many ran.  The output -- rows, counts, consumed bytes -- is
+// bit-identical for any worker count.
+int64_t asa_pack_batch(void* h, const char* buf, int64_t len, int final_,
                        int64_t max_lines, uint32_t* out, int64_t cap,
-                       int64_t* n_lines_out, int64_t* n_valid_out) {
-    return asa_pack_chunk_mt(h, buf, len, final_, max_lines, out, cap,
-                             n_lines_out, n_valid_out, 1);
-}
-
-// Dual-family chunk parse (v6-capable rulesets): v4 rows pack into the
-// [TUPLE_COLS, cap] plane exactly as asa_pack_chunk, v6 rows into the
-// [13, cap6] TUPLE6 plane (limb layout, pack.py).  Callers size
-// cap6 >= 2 * max_lines so the v6 side never closes a batch (mirrors
-// the Python _TextSource, whose v6 rows ride a side buffer and never
-// close a batch either).  ``n_threads`` splits the parse across workers
-// with the same slab/compaction structure as asa_pack_chunk_mt —
-// output, counters, and consumed bytes are bit-identical for any
-// thread count.  Returns bytes consumed.
-int64_t asa_pack_chunk2(void* h, const char* buf, int64_t len, int final_,
-                        int64_t max_lines, uint32_t* out, int64_t cap,
-                        uint32_t* out6, int64_t cap6,
-                        int64_t* n_lines_out, int64_t* n_valid_out,
-                        int64_t* n_valid6_out, int n_threads) {
+                       uint32_t* out6, int64_t cap6, int64_t* n_lines_out,
+                       int64_t* n_valid_out, int64_t* n_valid6_out,
+                       int n_threads, int full_batch, int* workers_out) {
     simd_init();
-    constexpr int64_t T6 = 13;  // TUPLE6_COLS
     Packer* pk = (Packer*)h;
-    const char* end = buf + len;
-    int64_t want = max_lines < cap ? max_lines : cap;
     const HandleLineFn handle = handle_line_fn();
-    if (n_threads != 1 && (len > (int64_t)0xFFFFFFFF || max_lines > cap))
-        n_threads = 1;  // same constraints as the v4 MT path
-
-    if (n_threads == 1) {
-        LocalCtx cx{&pk->resolve, {}};
-        const char* p = buf;
-        int64_t lines = 0, valid = 0, valid6 = 0;
-        int64_t parsed = 0, skipped = 0;
-        while (p < end && lines < max_lines) {
-            const char* nl = (const char*)memchr(p, '\n', end - p);
-            const char* le = nl ? nl : end;
-            if (!nl && !final_) break;  // incomplete tail line
-            int64_t v6_before = valid6;
-            int n = handle(&cx, p, le, out, cap, valid, out6, cap6, &valid6);
-            if (n < 0) break;  // rows don't fit: close batch, keep line
-            if (n == 0) ++skipped;
-            else {
-                parsed += n;
-                if (valid6 == v6_before) valid += n;  // v4 rows advanced
-            }
-            ++lines;
-            p = nl ? nl + 1 : end;
-        }
-        pk->parsed += parsed;
-        pk->skipped += skipped;
-        zero_tail(out, cap, valid);
-        for (int64_t c = 0; c < T6; ++c)
-            memset(out6 + c * cap6 + valid6, 0,
-                   (size_t)(cap6 - valid6) * sizeof(uint32_t));
-        *n_lines_out = lines;
-        *n_valid_out = valid;
-        *n_valid6_out = valid6;
-        return p - buf;
-    }
-
-    // ---- pass 1: line-offset index (as asa_pack_chunk_mt)
-    std::vector<uint32_t> off;
-    off.reserve((size_t)(want > 0 ? want + 1 : 1));
-    const char* p = build_line_index(buf, len, final_, want, off);
-    const int64_t L = (int64_t)off.size();
-    if (L == 0) {
-        zero_tail(out, cap, 0);
-        for (int64_t c = 0; c < T6; ++c)
-            memset(out6 + c * cap6, 0, (size_t)cap6 * sizeof(uint32_t));
-        *n_lines_out = 0;
-        *n_valid_out = 0;
-        *n_valid6_out = 0;
-        return 0;
-    }
-    const int64_t consumed = p - buf;
-    off.push_back((uint32_t)consumed);
-    auto line_end = [&](int64_t i) {
-        const char* q = buf + off[i + 1];
-        return (q > buf + off[i] && q[-1] == '\n') ? q - 1 : q;
-    };
-
-    int W = n_threads;
-    if (W <= 0) W = (int)std::thread::hardware_concurrency();
-    if (W < 1) W = 1;
-    if (W > (int)(L / 1024) + 1) W = (int)(L / 1024) + 1;
-
-    // ---- workers: private slabs per family + per-line row counts
-    std::vector<uint32_t> scratch4((size_t)(TUPLE_COLS * 2 * L));
-    std::vector<uint32_t> scratch6((size_t)(T6 * 2 * L));
-    std::vector<uint8_t> rows4_per_line((size_t)L);
-    std::vector<uint8_t> rows6_per_line((size_t)L);
-    std::vector<int64_t> lo(W + 1);
-    for (int w = 0; w <= W; ++w) lo[w] = L * w / W;
-    std::vector<LocalCtx> ctx((size_t)W);
-    std::vector<std::thread> threads;
-    threads.reserve((size_t)W);
-    for (int w = 0; w < W; ++w) {
-        ctx[w].resolve = &pk->resolve;
-        threads.emplace_back([&, w]() {
-            const int64_t i0 = lo[w], i1 = lo[w + 1];
-            const int64_t slab_cap = 2 * (i1 - i0);
-            uint32_t* slab4 = scratch4.data() + (size_t)(2 * i0 * TUPLE_COLS);
-            uint32_t* slab6 = scratch6.data() + (size_t)(2 * i0 * T6);
-            LocalCtx* cx = &ctx[w];
-            int64_t v4 = 0, v6 = 0;
-            for (int64_t i = i0; i < i1; ++i) {
-                int64_t v6_before = v6;
-                int n = handle(cx, buf + off[i], line_end(i),
-                               slab4, slab_cap, v4,
-                               slab6, slab_cap, &v6);
-                // n < 0 impossible: slab caps are 2 * range lines
-                if (n > 0 && v6 != v6_before) {
-                    rows6_per_line[(size_t)i] = (uint8_t)n;
-                } else {
-                    rows4_per_line[(size_t)i] = (uint8_t)(n > 0 ? n : 0);
-                    if (n > 0) v4 += n;
-                }
-            }
-        });
-    }
-    for (auto& t : threads) t.join();
-
-    // ---- line-atomic cap on the v4 plane only (cap6 >= 2*max_lines by
-    // the caller contract, so v6 rows can never close the batch)
-    int64_t K = 0, total4 = 0;
-    int64_t parsed = 0, skipped = 0;
-    for (; K < L; ++K) {
-        const int64_t r4 = rows4_per_line[(size_t)K];
-        const int64_t r6 = rows6_per_line[(size_t)K];
-        if (total4 + r4 > cap) break;
-        total4 += r4;
-        if (r4 == 0 && r6 == 0) ++skipped;
-        else parsed += r4 + r6;
-    }
-
-    // ---- compaction: per family, concatenating consumed lines' rows
-    int64_t valid = 0, valid6 = 0;
-    for (int w = 0; w < W && lo[w] < K; ++w) {
-        const int64_t i0 = lo[w], i1 = lo[w + 1] < K ? lo[w + 1] : K;
-        const int64_t slab_cap = 2 * (lo[w + 1] - i0);
-        const uint32_t* slab4 = scratch4.data() + (size_t)(2 * i0 * TUPLE_COLS);
-        const uint32_t* slab6 = scratch6.data() + (size_t)(2 * i0 * T6);
-        int64_t take4 = 0, take6 = 0;
-        for (int64_t i = i0; i < i1; ++i) {
-            take4 += rows4_per_line[(size_t)i];
-            take6 += rows6_per_line[(size_t)i];
-        }
-        for (int64_t c = 0; c < TUPLE_COLS; ++c)
-            memcpy(out + c * cap + valid, slab4 + c * slab_cap,
-                   (size_t)take4 * sizeof(uint32_t));
-        for (int64_t c = 0; c < T6; ++c)
-            memcpy(out6 + c * cap6 + valid6, slab6 + c * slab_cap,
-                   (size_t)take6 * sizeof(uint32_t));
-        valid += take4;
-        valid6 += take6;
-    }
-    pk->parsed += parsed;
-    pk->skipped += skipped;
-    zero_tail(out, cap, valid);
-    for (int64_t c = 0; c < T6; ++c)
-        memset(out6 + c * cap6 + valid6, 0,
-               (size_t)(cap6 - valid6) * sizeof(uint32_t));
-    *n_lines_out = K;
-    *n_valid_out = valid;
-    *n_valid6_out = valid6;
-    return K < L ? (int64_t)off[K] : consumed;
+    const int64_t want = max_lines < cap ? max_lines : cap;
+    int64_t W = n_threads > 0 ? n_threads : (int64_t)std::thread::hardware_concurrency();
+    if (W > want / 1024 + 1) W = want / 1024 + 1;  // tiny batches: few
+    if (W < 1 || len > (int64_t)0xFFFFFFFF || max_lines > cap) W = 1;
+    *workers_out = (int)W;
+    if (W == 1)
+        return pack_serial(pk, handle, buf, len, final_, full_batch, max_lines,
+                           out, cap, out6, cap6, n_lines_out, n_valid_out,
+                           n_valid6_out);
+    return pack_parallel(pk, handle, buf, len, final_, full_batch, want, (int)W,
+                         out, cap, out6, cap6, n_lines_out, n_valid_out,
+                         n_valid6_out);
 }
 
 // Plain newline count (streaming buffer bookkeeping; the SIMD popcount

@@ -9,10 +9,42 @@
 #pragma once
 
 #include <cstdint>
+#include <cstring>
+#include <memory>
 #include <string>
 #include <unordered_map>
+#include <vector>
 
 namespace ra_parse {
+
+// Grow-only array whose storage is never value-initialised: the parse
+// writes every element before it reads it, so zero-filling would only
+// add a pass over memory.  reserve() keeps the first `keep` elements
+// across a regrowth.
+template <class T>
+struct RawBuf {
+    std::unique_ptr<T[]> p;
+    size_t n = 0;
+    T* reserve(size_t want, size_t keep = 0) {
+        if (want > n) {
+            size_t m = want + want / 8;
+            std::unique_ptr<T[]> q(new T[m]);
+            if (keep) memcpy(q.get(), p.get(), keep * sizeof(T));
+            p = std::move(q);
+            n = m;
+        }
+        return p.get();
+    }
+};
+
+// One parse worker's buffers, kept by the Packer across batches.
+struct WorkerScratch {
+    RawBuf<uint32_t> nl;     // newline offsets in the worker's byte range
+    RawBuf<uint32_t> slab4;  // [TUPLE_COLS, 2 * lines] rows of its lines
+    RawBuf<uint32_t> slab6;  // [TUPLE6_COLS, 2 * lines] (v6-capable rulesets)
+    RawBuf<uint8_t> rows4;   // rows each of its lines emitted, per plane
+    RawBuf<uint8_t> rows6;
+};
 
 struct Packer {
     // key: firewall + '\x01' + acl   -> acl gid  (named-ACL messages)
@@ -21,6 +53,7 @@ struct Packer {
     std::unordered_map<std::string, uint32_t> resolve;
     int64_t parsed = 0;   // ACL evaluations emitted (LinePacker.parsed)
     int64_t skipped = 0;  // lines yielding none (LinePacker.skipped)
+    std::vector<WorkerScratch> scratch;  // grown to the most workers used
 };
 
 // Per-thread parse context: the shared resolve table is read-only during a

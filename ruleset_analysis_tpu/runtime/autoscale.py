@@ -147,8 +147,9 @@ class PolicyEngine:
     1. budget gone (``reform_budget`` actuations used) -> hold forever;
     2. within ``cooldown_sec`` of the previous decision -> hold;
     3. the sample window does not yet span ``sustain_sec`` -> hold
-       (the window resets after every decision: post-reform signals
-       describe a different capacity);
+       (the window keeps ``1.5 * sustain_sec`` of samples, and never
+       fewer than the last two; it resets after every decision:
+       post-reform signals describe a different capacity);
     4. min(pressure) over the window >= ``out_threshold`` and a higher
        rung exists -> scale OUT one rung;
     5. else min(starvation) >= ``in_threshold`` and a lower rung
@@ -258,7 +259,13 @@ class PolicyEngine:
         pressure = min(max(float(pressure), 0.0), 1.0)
         starvation = min(max(float(starvation), 0.0), 1.0)
         self._window.append((now, pressure, starvation))
-        while self._window and now - self._window[0][0] > a.sustain_sec * 1.5:
+        # the previous sample always stays: a loop blocked longer than the
+        # window (a compile, a slow step) still compares the readings on
+        # both sides of the gap instead of going blind while it is behind
+        while (
+            len(self._window) > 2
+            and now - self._window[0][0] > a.sustain_sec * 1.5
+        ):
             self._window.popleft()
 
         if self._plan:

@@ -71,9 +71,12 @@ class IngestStats:
     ``ingest.produce`` spans cover; ``pack_sec`` is the pack transform
     (coalescing, bit-pack and the async sharded ``device_put`` issue) —
     what ``ingest.pack`` covers; ``produce_sec`` is their sum.
-    ``backpressure_sec`` is producer time blocked on a full queue (the
-    device is the bottleneck); ``starved_sec`` is consumer time blocked
-    on an empty queue (the parse is the bottleneck).  The report totals
+    ``parse_workers`` and ``parse_serial_batches`` (native text sources
+    only) are the workers the last batch parsed in parallel used and the
+    batches that took the one-thread loop.  ``backpressure_sec`` is
+    producer time blocked on a full queue (the device is the
+    bottleneck); ``starved_sec`` is consumer time blocked on an empty
+    queue (the parse is the bottleneck).  The report totals
     carry these so "parse-starved vs device-bound" is answerable from
     any run's JSON.
     """
@@ -84,6 +87,8 @@ class IngestStats:
         self.backpressure_sec = 0.0
         self.starved_sec = 0.0
         self.batches = 0
+        self.parse_workers: int | None = None
+        self.parse_serial_batches: int | None = None
 
     @property
     def produce_sec(self) -> float:
@@ -91,10 +96,15 @@ class IngestStats:
 
     def to_dict(self) -> dict:
         parse, pack = round(self.parse_sec, 4), round(self.pack_sec, 4)
+        workers = {} if self.parse_serial_batches is None else {
+            "parse_workers": self.parse_workers,
+            "parse_serial_batches": self.parse_serial_batches,
+        }
         return {
             "batches": self.batches,
             "produce_sec": round(parse + pack, 4),
             "parse_sec": parse,
+            **workers,
             "pack_sec": pack,
             "backpressure_sec": round(self.backpressure_sec, 4),
             "starved_sec": round(self.starved_sec, 4),
@@ -402,6 +412,10 @@ class PrefetchingSource:
 
     # -- lifecycle ------------------------------------------------------
     def ingest_stats(self) -> dict:
+        pk = getattr(self._inner, "packer", None)
+        if hasattr(pk, "parse_serial_batches"):  # the native text parser
+            self.stats.parse_workers = pk.parse_workers
+            self.stats.parse_serial_batches = pk.parse_serial_batches
         return {"prefetch_depth": self.depth, **self.stats.to_dict()}
 
     def latency_summary(self) -> dict:

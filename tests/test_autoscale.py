@@ -198,6 +198,26 @@ def test_engine_window_resets_after_decision():
     assert t2 - t >= eng.acfg.sustain_sec
 
 
+def test_engine_sees_pressure_across_samples_sparser_than_the_window():
+    """A serve loop blocked in a compile samples slower than the window
+    keeps (1.5 * sustain_sec): two readings across the gap still count,
+    one reading alone never does, and a low reading still holds."""
+    eng = PolicyEngine(acfg(sustain_sec=0.5), world=2, ladder=[2, 4])
+    assert eng.observe(now=0.0, pressure=0.9, starvation=0.0) is None
+    d = eng.observe(now=1.2, pressure=0.8, starvation=0.0)
+    assert d is not None and (d.direction, d.to_world) == ("out", 4)
+    assert d.evidence["samples"] == 2 and d.evidence["window_sec"] == 1.2
+    # a reading below threshold on either side of the gap holds
+    eng = PolicyEngine(acfg(sustain_sec=0.5), world=2, ladder=[2, 4])
+    assert eng.observe(now=0.0, pressure=0.1, starvation=0.0) is None
+    assert eng.observe(now=1.2, pressure=0.9, starvation=0.0) is None
+    # dense samples are trimmed to the window as before: the low reading
+    # ages out and sustained pressure decides
+    d, t = feed(eng, 1.45, 5.0, p=0.9)
+    assert d is not None and t - 1.2 >= 0.5
+    assert d.evidence["window_sec"] <= 0.75
+
+
 def test_world_ladder_and_plan_parsing():
     assert world_ladder(1, 8) == [1, 2, 3, 4, 5, 6, 7, 8]
     assert world_ladder(2, 8, divisors_of=8) == [2, 4, 8]
@@ -643,8 +663,9 @@ def _wait(pred, timeout, msg):
 
 def test_serve_autoscale_e2e_burst_out_idle_in(serve_corpus, tmp_path):
     """The acceptance run: a traffic burst scales the serve mesh OUT
-    (threshold-driven, evidence attached), the post-burst idle scales it
-    back IN after cooldown, zero flaps, zero drops — and every published
+    (threshold-driven, evidence attached), a steady trickle holds it past
+    the damping window, the idle after it scales the mesh back IN, zero
+    flaps, zero drops — and every published
     window is bit-identical to an offline replay of exactly its lines.
     """
     import urllib.request
@@ -680,6 +701,19 @@ def test_serve_autoscale_e2e_burst_out_idle_in(serve_corpus, tmp_path):
             lambda: any(d.direction == "out" for d in drv._engine.decisions),
             90, "scale-out under burst",
         )
+        # steady: a light load (no pressure, never starved) flows until the
+        # damping window has passed since the last decision, so the idle
+        # that follows is a change of load, not a reversal of the burst's
+        # decision racing the host's drain speed.  Every burst line is
+        # queued first, so the first windows hold exactly lines[:3000].
+        _wait(lambda: drv.queue.received >= len(lines), 30, "the whole burst")
+        damping = 2 * (a.cooldown_sec + a.sustain_sec)
+        with socket.create_connection(drv.listeners.listeners[0].address) as s:
+            i = 0
+            while time.monotonic() - drv._engine.decisions[-1].t < damping + 0.5:
+                s.sendall(("\n".join(lines[i:i + 4]) + "\n").encode())
+                i = (i + 4) % len(lines)
+                time.sleep(0.05)
         # idle: the queue drains, starvation sustains, the mesh comes back
         _wait(
             lambda: any(d.direction == "in" for d in drv._engine.decisions),

@@ -93,6 +93,18 @@ def _synth_case(n=4000, seed=0):
     return packed, lines
 
 
+def _egress_case(n, seed):
+    """A ruleset with out-bindings: connection lines emit two rows."""
+    cfg_text = synth.synth_config(
+        n_acls=2, rules_per_acl=12, seed=seed, egress_acls=True
+    )
+    rs = aclparse.parse_asa_config(cfg_text, "fw1")
+    packed = pack.pack_rulesets([rs])
+    tuples = synth.synth_tuples(packed, n, seed=seed + 1)
+    lines = synth.render_syslog(packed, tuples, seed=seed + 2, variety=0.6)
+    return packed, lines
+
+
 def _both(packed, lines, batch_size):
     py = pack.LinePacker(packed)
     ref = py.pack_lines(lines, batch_size=batch_size)
@@ -134,21 +146,88 @@ class TestParity:
         assert n_lines == 1 and used == len(line.encode())
         assert out[6, 0] == 1 and out[6, 1] == 0
 
+    @pytest.mark.parametrize(
+        "shape", ["lf", "crlf", "fragment_final", "fragment_held", "dual_rows"]
+    )
     @pytest.mark.parametrize("n_threads", [2, 3, 8])
-    def test_multithread_bit_identical(self, n_threads):
-        """The parallel parse (worker slabs + compaction) must produce the
-        same batch, counters, and consumed bytes as one thread — for any
-        thread count, including more workers than lines per split."""
-        packed, lines = _synth_case(n=5000, seed=3)
-        corpus = lines + EDGE_LINES  # include skipped/edge lines mid-stream
-        data = ("\n".join(corpus) + "\n").encode()
+    def test_multithread_bit_identical(self, n_threads, shape):
+        """The parallel pass (per-worker newline scan, slabs, parallel
+        compaction) must produce the same batch, counters, and consumed
+        bytes as one thread — for any thread count, including more
+        workers than lines per split, on CRLF lines, an unterminated
+        last line (parsed when final, held back otherwise), and dual-row
+        lines that close the batch early."""
+        if shape == "dual_rows":
+            packed, corpus = _egress_case(n=5000, seed=3)
+            cap = len(corpus)  # two-row lines overflow it: an early close
+        else:
+            packed, lines = _synth_case(n=5000, seed=3)
+            corpus = lines[:2500] + EDGE_LINES + lines[2500:]  # edge lines mid-stream
+            cap = len(corpus)
+        sep = "\r\n" if shape == "crlf" else "\n"
+        tail = "" if shape.startswith("fragment") else sep
+        data = (sep.join(corpus) + tail).encode()
+        final = shape != "fragment_held"
         nat1 = fastparse.NativePacker(packed)
-        out1, l1, u1 = nat1.pack_chunk(data, len(corpus), final=True, n_threads=1)
+        out1, l1, u1 = nat1.pack_chunk(data, cap, final=final, n_threads=1)
         natn = fastparse.NativePacker(packed)
-        outn, ln, un = natn.pack_chunk(data, len(corpus), final=True, n_threads=n_threads)
+        outn, ln, un = natn.pack_chunk(data, cap, final=final, n_threads=n_threads)
         np.testing.assert_array_equal(out1, outn)
         assert (l1, u1) == (ln, un)
         assert (nat1.parsed, nat1.skipped) == (natn.parsed, natn.skipped)
+        assert natn.parse_workers == min(n_threads, cap // 1024 + 1)
+        assert nat1.parse_serial_batches == 1 and natn.parse_serial_batches == 0
+        if shape == "dual_rows":
+            assert ln < len(corpus)
+        elif shape == "fragment_held":
+            assert ln == len(corpus) - 1 and un < len(data)
+        else:
+            assert ln == len(corpus) and un == len(data)
+
+    def test_parallel_pass_stress_more_workers_than_cores(self):
+        """32 workers (more than the cores here) meet at the pass's two
+        barriers, again and again on one packer's persistent slabs: every
+        repeat finishes in bounded time with the one-thread loop's batch."""
+        import threading
+
+        packed, lines = _egress_case(n=40000, seed=12)
+        data = ("\n".join(lines) + "\n").encode()
+        cap = 40000
+        ref, ref_lines, ref_used = fastparse.NativePacker(packed).pack_chunk(
+            data, cap, final=True, n_threads=1
+        )
+        nat = fastparse.NativePacker(packed)
+        got = []
+
+        def repeat():
+            for _ in range(20):
+                got.append(nat.pack_chunk(data, cap, final=True, n_threads=32))
+
+        t = threading.Thread(target=repeat, daemon=True)
+        t.start()
+        t.join(timeout=120)
+        assert not t.is_alive() and len(got) == 20
+        assert nat.parse_workers == 32
+        for out, n_lines, used in got:
+            assert (n_lines, used) == (ref_lines, ref_used)
+            np.testing.assert_array_equal(out, ref)
+
+    def test_full_batch_reports_short_buffer(self):
+        """With full_batch and final=False, a buffer holding fewer than
+        max_lines complete lines is left unparsed, on both paths: the call
+        names the complete lines held and moves no counter."""
+        packed, lines = _synth_case(n=3000, seed=5)
+        data = ("\n".join(lines) + "\n").encode()
+        for n_threads in (1, 3):
+            nat = fastparse.NativePacker(packed)
+            got = nat.pack_chunk(data, 4096, final=False, n_threads=n_threads,
+                                 full_batch=True)
+            assert got == (None, 3000, 0)
+            assert (nat.parsed, nat.skipped) == (0, 0)
+            out, n_lines, used = nat.pack_chunk(
+                data, 4096, final=True, n_threads=n_threads, full_batch=True
+            )
+            assert (n_lines, used) == (3000, len(data))
 
     def test_multithread_respects_max_lines(self):
         packed, lines = _synth_case(n=3000, seed=4)
@@ -262,6 +341,85 @@ class TestFileStream:
             rb = np.ascontiguousarray(py.pack_lines(c, batch_size=128).T)
             np.testing.assert_array_equal(gb, rb)
 
+    @pytest.mark.parametrize(
+        "case",
+        ["uneven", "chained", "crlf", "long_line", "resume", "dual_rows", "fragment"],
+    )
+    @pytest.mark.parametrize("n_threads", [2, 3, 8])
+    def test_stream_parallel_matches_one_thread(
+        self, tmp_path, monkeypatch, n_threads, case
+    ):
+        """batches_from_files through the parallel pass yields exactly the
+        batches, line counts and counters of the one-thread loop: a batch
+        size that does not divide the corpus, chained files (the first
+        without a trailing newline), CRLF lines, a line longer than the
+        read block, a mid-file resume, two-row lines that close batches
+        early, and a final unterminated fragment."""
+        n, bs = 20000, 7500
+        if case == "dual_rows":
+            packed, lines = _egress_case(n=n, seed=7)
+        else:
+            packed, lines = _synth_case(n=n, seed=7)
+        if case == "long_line":
+            lines.insert(9000, "x" * 100_000)
+        sep = "\r\n" if case == "crlf" else "\n"
+        if case == "chained":
+            p1, p2 = tmp_path / "a.log", tmp_path / "b.log"
+            p1.write_bytes(sep.join(lines[:8333]).encode())
+            p2.write_bytes((sep.join(lines[8333:]) + sep).encode())
+            paths = [str(p1), str(p2)]
+        else:
+            p = tmp_path / "c.log"
+            p.write_bytes((sep.join(lines) + ("" if case == "fragment" else sep)).encode())
+            paths = [str(p)]
+        skip = 12345 if case == "resume" else 0
+        runs = {}
+        for t in (1, n_threads):
+            monkeypatch.setenv("RA_PARSE_THREADS", str(t))
+            nat = fastparse.NativePacker(packed)
+            runs[t] = (
+                list(fastparse.batches_from_files(
+                    paths, nat, bs, skip_lines=skip, read_block=1 << 16
+                )),
+                nat,
+            )
+        (ref, nat1), (got, natn) = runs[1], runs[n_threads]
+        counts = [k for _, k in ref]
+        assert counts == [k for _, k in got]
+        for (a, _), (b, _) in zip(ref, got):
+            np.testing.assert_array_equal(a, b)
+        assert (nat1.parsed, nat1.skipped) == (natn.parsed, natn.skipped)
+        assert (natn.parse_workers, natn.parse_serial_batches) == (n_threads, 0)
+        assert (nat1.parse_workers, nat1.parse_serial_batches) == (0, len(ref))
+        assert sum(counts) == len(lines) - skip
+        if case == "dual_rows":
+            assert min(counts[:-1]) < bs  # two-row lines closed a batch early
+        else:
+            assert counts[:-1] == [bs] * (len(counts) - 1)
+
+    def test_persistent_slabs_leave_no_stale_rows(self, tmp_path, monkeypatch):
+        """The workers' slabs persist across batches: a short batch after
+        full ones has all-zero padding and no stale rows, and each yielded
+        batch is an array of its own that later parses do not overwrite."""
+        monkeypatch.setenv("RA_PARSE_THREADS", "4")
+        packed, lines = _synth_case(n=10000, seed=8)
+        path = self._write(tmp_path, lines)
+        nat = fastparse.NativePacker(packed)
+        held = [(b, b.copy(), k) for b, k in fastparse.batches_from_file(path, nat, 4096)]
+        assert [k for *_, k in held] == [4096, 4096, 1808]
+        assert nat.parse_workers == 4
+        for (a, _, _), (b, _, _) in zip(held, held[1:]):
+            assert not np.shares_memory(a, b)
+        for batch, snap, _ in held:
+            np.testing.assert_array_equal(batch, snap)
+        last = held[-1][0]
+        valid = int(last[pack.T_VALID].sum())
+        assert 0 < valid <= 1808 and not last[:, valid:].any()
+        ref, _, _ = fastparse.NativePacker(packed).pack_chunk(
+            ("\n".join(lines[8192:]) + "\n").encode(), 4096, final=True, n_threads=1
+        )
+        np.testing.assert_array_equal(last, ref)
+
     def test_count_lines(self, tmp_path):
         p = tmp_path / "c.log"
         p.write_bytes(b"a\nb\nc")
@@ -291,6 +449,29 @@ class TestDriver:
         assert rep_nat.unused == rep_text.unused
         assert rep_nat.totals["lines_total"] == rep_text.totals["lines_total"]
         assert rep_nat.totals["lines_matched"] == rep_text.totals["lines_matched"]
+
+    @pytest.mark.parametrize("threads", [1, 2])
+    def test_report_counts_parse_workers(self, tmp_path, monkeypatch, threads):
+        """totals.ingest says how the native parse ran: the workers of the
+        last parallel batch, and the batches that took the one-thread
+        loop (every batch under RA_PARSE_THREADS=1)."""
+        from ruleset_analysis_tpu.runtime.stream import run_stream_file
+
+        monkeypatch.setenv("RA_PARSE_THREADS", str(threads))
+        packed, lines = _synth_case(n=5000)
+        path = tmp_path / "s.log"
+        path.write_text("\n".join(lines) + "\n", encoding="utf-8")
+        cfg = AnalysisConfig(
+            batch_size=2048,
+            sketch=SketchConfig(cms_width=1 << 10, cms_depth=4, hll_p=6),
+        )
+        rep = run_stream_file(packed, str(path), cfg, native=True)
+        ing = rep.totals["ingest"]
+        assert rep.totals["chunks"] == 3
+        if threads > 1:
+            assert ing["parse_workers"] == 2 and ing["parse_serial_batches"] == 0
+        else:
+            assert ing["parse_workers"] == 0 and ing["parse_serial_batches"] == 3
 
     def test_checkpoint_resume_native(self, tmp_path):
         from ruleset_analysis_tpu.runtime.stream import run_stream_file

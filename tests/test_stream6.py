@@ -389,7 +389,7 @@ def test_v6_adversarial_endpoint_parity():
 
 
 def test_native_v6_mt_bit_identical_to_single_thread():
-    """asa_pack_chunk2's worker/compaction path (n_threads>1) must match
+    """The v6 plane through the parallel pass (n_threads>1) must match
     the sequential loop bit-for-bit, including line-atomic batch closes."""
     from ruleset_analysis_tpu.hostside import fastparse, synth
 
