@@ -40,7 +40,7 @@ import numpy as np
 from . import fastparse
 from ..errors import FeedWorkerError, StallError
 from ..runtime import faults, obs
-from .pack import PackedRuleset, TUPLE_COLS, TUPLE6_COLS
+from .pack import PackedRuleset, TUPLE_COLS, TUPLE6_COLS, V6DigestFill
 
 #: Coordinator read granularity while scanning for batch boundaries.
 SCAN_BLOCK = 8 << 20
@@ -243,6 +243,7 @@ class _FeederBase:
         #: digest -> 128-bit source for talker rendering (same contract
         #: as the other sources)
         self.v6_digests: dict[int, int] = {}
+        self._v6_fill = V6DigestFill(self.v6_digests)
 
     def set_counts(self, parsed: int, skipped: int) -> None:
         self._resume_counts = (parsed, skipped)
@@ -259,14 +260,7 @@ class _FeederBase:
 
     def _stage_v6(self, rows6: np.ndarray) -> None:
         """Commit one batch's v6 rows + talker digests, in input order."""
-        from .pack import T6_SRC, V6_DIGEST_CAP, fold_src32_host, limbs_u128
-
-        dig = self.v6_digests
-        for r in rows6:
-            if len(dig) >= V6_DIGEST_CAP:
-                break
-            src = limbs_u128(*r[T6_SRC:T6_SRC + 4])
-            dig.setdefault(fold_src32_host(src), src)
+        self._v6_fill.add_rows(rows6)
         self._v6chunks.append(rows6)
 
 

@@ -193,6 +193,69 @@ def fold_src32_host(v: int) -> int:
     h = ((h ^ l3) * 0x27D4EB2F) & m
     return h ^ (h >> 15)
 
+
+class V6DigestFill:
+    """Fills a v6 talker map (fold digest -> 128-bit source) a batch at a time.
+
+    The map keeps what a per-row ``setdefault`` loop in stream order would
+    keep: the first source seen for each digest, in first-seen order, until
+    it holds :data:`V6_DIGEST_CAP` entries.  It stays a plain dict for its
+    readers; beside it a sorted array of its keys answers "seen before?" for
+    a whole batch at once, so Python only builds the address of a digest
+    the map lacks.
+    """
+
+    def __init__(self, dig: dict[int, int]):
+        self.dig = dig
+        self._known = np.empty(0, dtype=np.uint32)  # sorted keys of dig
+
+    def add(self, limbs: np.ndarray) -> None:
+        """Fold ``[4, n]`` uint32 source limbs, in stream order, into the map."""
+        dig = self.dig
+        room = V6_DIGEST_CAP - len(dig)
+        if room <= 0 or not limbs.shape[1]:
+            return
+        known = self._known
+        if known.size != len(dig):  # entries put in by a resume's restore
+            known = np.sort(np.fromiter(dig, dtype=np.uint32, count=len(dig)))
+        folds = fold_src32_np(limbs)
+        # a header repeats in runs: keep the first row of each run
+        runs = np.empty(folds.size, dtype=bool)
+        runs[0] = True
+        np.not_equal(folds[1:], folds[:-1], out=runs[1:])
+        rows = np.flatnonzero(runs)
+        f = folds[rows]
+        if known.size:
+            # look the batch's digests up in sorted order (a sorted search
+            # walks the known keys in order: several times faster)
+            s = np.sort(f)
+            s = np.unique(s[~_in_sorted(known, s)])  # digests new to the map
+            new = _in_sorted(s, f) if s.size else np.zeros(f.size, dtype=bool)
+            rows, f = rows[new], f[new]
+        if f.size:
+            # first row of each new digest, in stream order, up to the cap
+            first = np.sort(np.unique(f, return_index=True)[1])[:room]
+            rows, f = rows[first], f[first]
+            l0, l1, l2, l3 = limbs[:, rows].tolist()
+            dig.update(zip(f.tolist(), [
+                (a << 96) | (b << 64) | (c << 32) | d
+                for a, b, c, d in zip(l0, l1, l2, l3)
+            ]))
+            f.sort()
+            known = np.insert(known, np.searchsorted(known, f), f)
+        self._known = known
+
+    def add_rows(self, rows6) -> None:
+        """:meth:`add` over ``[n, TUPLE6_COLS]`` v6 tuple rows."""
+        if len(rows6):
+            self.add(np.asarray(rows6, dtype=np.uint32)[:, T6_SRC:T6_SRC + 4].T)
+
+
+def _in_sorted(keys: np.ndarray, v: np.ndarray) -> np.ndarray:
+    """Mask of the entries of ``v`` found in the sorted, non-empty ``keys``."""
+    return keys[np.minimum(np.searchsorted(keys, v), keys.size - 1)] == v
+
+
 #: acl gid budget in the wire meta word: 23 bits (proto takes 8, valid 1).
 WIRE_MAX_ACLS = 1 << 23
 

@@ -168,9 +168,6 @@ class _TextSource:
     rows) without stepping an all-invalid device chunk — ADVICE r5 #3.
     """
 
-    #: one shared knob for every source tier (see pack.V6_DIGEST_CAP)
-    V6_DIGEST_CAP = pack_mod.V6_DIGEST_CAP
-
     def __init__(self, packed: PackedRuleset, lines: Iterable[str]):
         self.packer = LinePacker(packed)
         self._lines = lines
@@ -342,6 +339,7 @@ class _WireFileSource:
         #: fold digest -> 128-bit source (populated by batches6; report
         #: rendering of v6 talkers, same contract as _TextSource)
         self.v6_digests: dict[int, int] = {}
+        self._v6_fill = pack_mod.V6DigestFill(self.v6_digests)
 
     def set_counts(self, parsed: int, skipped: int) -> None:
         self.packer.parsed, self.packer.skipped = parsed, skipped
@@ -448,11 +446,8 @@ class _WireFileSource:
         """Wire-v2 v6 section (consumed after the v4 stream — phase 2)."""
         import numpy as _np
 
-        from ..hostside.pack import (
-            W6_META, W6_SRC, fold_src32_np, limbs_u128,
-        )
+        from ..hostside.pack import W6_META, W6_SRC
 
-        cap = _TextSource.V6_DIGEST_CAP
         for w6, n in self.reader.iter_batches6(skip_rows6, batch_size):
             v = int(_np.count_nonzero(w6[W6_META] & _np.uint32(1 << 23)))
             if self.weighted:
@@ -464,22 +459,7 @@ class _WireFileSource:
             else:
                 self.packer.parsed += v
             self.packer.skipped += (w6.shape[1] - v) - (w6.shape[1] - n)
-            if len(self.v6_digests) < cap and n:
-                # digest -> address map for talker rendering: vectorized
-                # fold + unique first, so the Python dict loop touches
-                # each DISTINCT source once per batch, not each row
-                limbs = w6[W6_SRC:W6_SRC + 4, :n]
-                folds = fold_src32_np(limbs)
-                _, idx = _np.unique(folds, return_index=True)
-                idx.sort()  # stream order: first-seen wins at the cap,
-                # matching _TextSource's documented contract
-                dig = self.v6_digests
-                for j in idx:
-                    f = int(folds[j])
-                    if f not in dig:
-                        if len(dig) >= cap:
-                            break
-                        dig[f] = limbs_u128(*limbs[:, int(j)])
+            self._v6_fill.add(w6[W6_SRC:W6_SRC + 4, :n])
             yield w6, n
 
     def close(self) -> None:
@@ -588,18 +568,6 @@ def _restore_v6_digests(source, snap) -> None:
         dig.setdefault(int(d), int(s))
 
 
-def _stage_v6_digests(rows, dig: dict[int, int]) -> None:
-    """Fold native-parser v6 rows into the capped digest->address map."""
-    if not len(rows):
-        return
-    cap = _TextSource.V6_DIGEST_CAP
-    for r in rows:
-        if len(dig) >= cap:
-            break
-        src = pack_mod.limbs_u128(*r[pack_mod.T6_SRC:pack_mod.T6_SRC + 4])
-        dig.setdefault(pack_mod.fold_src32_host(src), src)
-
-
 class _FileSource:
     """Batch source over syslog file(s) via the native C++ parser."""
 
@@ -610,6 +578,7 @@ class _FileSource:
         self._paths = paths
         self._has_v6 = packed.has_v6
         self.v6_digests: dict[int, int] = {}
+        self._v6_fill = pack_mod.V6DigestFill(self.v6_digests)
 
     def set_counts(self, parsed: int, skipped: int) -> None:
         self.packer.set_counts(parsed, skipped)
@@ -620,7 +589,7 @@ class _FileSource:
             return []
         with self.packer.v6_plane():
             rows = self.packer.take_v6()
-            _stage_v6_digests(rows, self.v6_digests)
+            self._v6_fill.add_rows(rows)
         return rows
 
     def batches(self, skip_lines: int, batch_size: int) -> Iterator[tuple[np.ndarray, int]]:
@@ -664,6 +633,7 @@ class _ShardCursorSource:
         self._native = native
         self._has_v6 = packed.has_v6
         self.v6_digests: dict[int, int] = {}
+        self._v6_fill = pack_mod.V6DigestFill(self.v6_digests)
         #: shard_index -> raw lines of that shard assigned to emitted batches
         self.cursors = {int(i): int(start) for i, _p, start in self._assignments}
         self.done: set[int] = set()
@@ -690,7 +660,7 @@ class _ShardCursorSource:
     def take_v6(self):
         if self._native:
             rows = self.packer.take_v6()
-            _stage_v6_digests(rows, self.v6_digests)
+            self._v6_fill.add_rows(rows)
             return rows
         out: list[tuple] = []
         for sub in self._subs:
@@ -2084,6 +2054,10 @@ def _run_core_impl(
     # the v6 side path's share of ``totals.ingest``: seconds in its
     # staging on this thread, v6 evaluations taken, v6 step dispatches
     v6_acct = {"v6_stage_sec": 0.0, "v6_lines": 0, "v6_chunks": 0}
+    # the talker map after any resume's restore: the digests this call adds
+    # are ``v6_digest_inserts`` (where pack.V6DigestFill fills the map, the
+    # only v6 rows that reach a Python loop)
+    digests_at_start = len(getattr(source, "v6_digests", None) or ())
 
     @contextlib.contextmanager
     def staging6():
@@ -2314,6 +2288,9 @@ def _run_core_impl(
             **stats_fn(),
             **v6_acct,
             "v6_stage_sec": round(v6_acct["v6_stage_sec"], 4),
+            "v6_digest_inserts": (
+                len(getattr(source, "v6_digests", None) or ()) - digests_at_start
+            ),
         }
     lat_fn = getattr(source, "latency_summary", None)
     if lat_fn is not None:

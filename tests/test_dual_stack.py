@@ -8,7 +8,8 @@ v6 share of 0, 0.45 and 1, through the native and the Python text
 sources.  Hits and the unused list must equal ``hostside/oracle.py``'s;
 unique sources and talkers must read as ``benchmark/reference_dual.py``
 says they must.  The v6 counters of ``totals.ingest`` count what the
-oracle saw, and read 0 on a pure-v4 run.
+oracle saw (the talker map's inserts, its distinct v6 sources), and read 0
+on a pure-v4 run.
 """
 
 import importlib
@@ -83,6 +84,10 @@ def _v6_lines(res) -> int:
     return sum(c for t in res.talkers.values() for (fam, _), c in t.items() if fam == 6)
 
 
+def _v6_sources(res) -> int:
+    return len({src for t in res.talkers.values() for fam, src in t if fam == 6})
+
+
 @pytest.mark.parametrize("source", ["native", "python"])
 def test_dual_stack_run_matches_oracle_and_reference(bench, corpus, source):
     reference_dual = bench[2]
@@ -116,6 +121,9 @@ def test_dual_stack_run_matches_oracle_and_reference(bench, corpus, source):
     # the v6 counters count what the oracle saw as IPv6
     ing = got["totals"]["ingest"]
     assert ing["v6_lines"] == _v6_lines(res)
+    # the talker map gained one entry per distinct v6 source (none collide
+    # at this size), and nothing on a pure-v4 run
+    assert ing["v6_digest_inserts"] == _v6_sources(res)
     if share:
         assert ing["v6_chunks"] >= 1 and ing["v6_stage_sec"] > 0
         assert ing["v6_plane_sec"] > 0 if source == "native" else ing["v6_plane_sec"] == 0

@@ -12,14 +12,21 @@ import pytest
 pytest.importorskip("jax")
 
 from ruleset_analysis_tpu.config import AnalysisConfig, SketchConfig
-from ruleset_analysis_tpu.hostside import aclparse, fastparse, oracle, pack, synth
+from ruleset_analysis_tpu.hostside import aclparse, fastparse, oracle, pack, synth, wire
 from ruleset_analysis_tpu.hostside.feeder import (
     ParallelFeeder,
     RingFeeder,
     ThreadedFeeder,
     _scan_batches,
 )
-from ruleset_analysis_tpu.runtime.stream import run_stream_file
+from ruleset_analysis_tpu.runtime.stream import (
+    _FileSource,
+    _WireFileSource,
+    run_stream_file,
+    run_stream_wire,
+)
+
+from tests.test_v6_digests import per_row_fill
 
 pytestmark = pytest.mark.skipif(
     not fastparse.available(), reason="native parser not buildable here"
@@ -248,18 +255,47 @@ def test_feeder_v6_plane_byte_identical_to_sequential(corpus6, tier):
     assert np.array_equal(seq4, par4)
     assert np.array_equal(seq6, par6)
     # capped digest->address map: same rows in the same stream order ->
-    # identical first-seen winners
-    from ruleset_analysis_tpu.hostside.pack import (
-        T6_SRC, V6_DIGEST_CAP, fold_src32_host, limbs_u128,
-    )
-
+    # identical first-seen winners, in the same order
     want: dict[int, int] = {}
-    for r in seq6:
-        if len(want) >= V6_DIGEST_CAP:
-            break
-        src = limbs_u128(*r[T6_SRC:T6_SRC + 4])
-        want.setdefault(fold_src32_host(src), src)
-    assert feeder.v6_digests == want
+    per_row_fill(want, seq6)
+    assert list(feeder.v6_digests.items()) == list(want.items())
+
+
+def test_v6_digest_map_same_on_native_and_wire_paths(corpus6, tmp_path):
+    """The text-native source and the wire's v6 section fill the talker
+    map as the feeders do (above): what the per-row loop gives over the
+    sequential parse's v6 rows.  ``totals.ingest.v6_digest_inserts``
+    counts its entries on each path: one per distinct v6 source."""
+    packed, paths = corpus6
+    packer = fastparse.NativePacker(packed)
+    _seq4, seq6 = _row_streams(
+        fastparse.batches_from_files(paths, packer, 256), packer.take_v6
+    )
+    want: dict[int, int] = {}
+    per_row_fill(want, seq6)
+    assert want and len(want) == len({tuple(r) for r in seq6[:, pack.T6_SRC:pack.T6_SRC + 4].tolist()})
+
+    native = _FileSource(packed, paths)
+    for _batch in native.batches(0, 256):
+        native.take_v6()
+    out = str(tmp_path / "mixed.rawire")
+    wire.convert_logs(packed, paths, out)
+    wired = _WireFileSource(packed, [out])
+    for _batch in wired.batches(0, 256):
+        pass
+    for _batch in wired.batches6(0, 256):
+        pass
+    wired.close()
+    for dig in (native.v6_digests, wired.v6_digests):
+        assert list(dig.items()) == list(want.items())
+
+    cfg = AnalysisConfig(
+        batch_size=256, sketch=SketchConfig(cms_width=1 << 11, cms_depth=4, hll_p=6)
+    )
+    for rep in (run_stream_file(packed, paths, cfg),
+                run_stream_file(packed, paths, cfg, feed_workers=2, feed_mode="thread"),
+                run_stream_wire(packed, out, cfg)):
+        assert rep.totals["ingest"]["v6_digest_inserts"] == len(want)
 
 
 # ---------------------------------------------------------------------------
