@@ -40,23 +40,23 @@ obs:
 		tests/test_devprof.py tests/test_lineage.py -q -m 'not slow' \
 		--continue-on-collection-errors -p no:cacheprovider
 
-# Multi-host serve scaling acceptance (DESIGN §22): 1-host vs 2-host
-# loopback soak over the same corpus — merged distributed windows must
-# be bit-identical to the single-host replay of the union, with zero
-# silent drops and a whole-host-kill chaos leg.  Writes the
-# SERVESCALE_r19_cpu.json evidence artifact shape.  Same 1-core caveat:
-# never run concurrently with the tier-1 gate.
+# Multi-host serve acceptance (DESIGN §22): merged 2-host distributed
+# windows and the cumulative report bit-identical to the single-host
+# replay of the union with zero drops, and a whole-host kill that names
+# the dead host in the window's incomplete marker and loses none of a
+# survivor's lines.  Exit-coded; same 1-core caveat as the gate above.
 servescale:
-	JAX_PLATFORMS=cpu python bench_suite.py servescale
+	JAX_PLATFORMS=cpu python -m pytest tests/test_distserve.py -q \
+		-m 'not slow' --continue-on-collection-errors -p no:cacheprovider
 
 # Durable epoch-store acceptance (DESIGN §25): segment-tree range
-# queries >= 10x a naive linear fold and bit-identical to it, the
-# spill-armed serve within 2% of disarmed, and a mid-compaction crash
-# leaving a readable store with zero lost epochs.  Writes the
-# EPOCHSTORE_r22_cpu.json evidence artifact shape.  Same 1-core caveat:
-# never run concurrently with the tier-1 gate.
+# queries bit-identical to a naive linear fold, a serve range report
+# answered without replay, and a mid-compaction crash leaving a
+# readable store with zero lost epochs.  Exit-coded; same 1-core caveat
+# as the gate above.
 epochstore:
-	JAX_PLATFORMS=cpu python bench_suite.py epochstore
+	JAX_PLATFORMS=cpu python -m pytest tests/test_epochstore.py -q \
+		-m 'not slow' --continue-on-collection-errors -p no:cacheprovider
 
 # Doctor acceptance path (DESIGN §20): chaos-killed runs must leave a
 # complete postmortem bundle the doctor can diagnose (failing stage +

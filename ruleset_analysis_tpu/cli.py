@@ -1481,17 +1481,20 @@ def make_parser() -> argparse.ArgumentParser:
                    help="per-ACL lane width for --layout=stacked (0 = auto)")
     p.add_argument("--match-impl", choices=["xla", "pallas"],
                    default="xla",
-                   help="first-match kernel (bench_suite.py pallas compares them)")
+                   help="first-match kernel (pallas runs in no benchmark cell; it "
+                        "lost to xla on the TPU in round 5, DESIGN §3)")
     p.add_argument("--experimental-match-impl", choices=["pallas_fused"],
                    default=None, metavar="IMPL",
                    help="enable an EXPERIMENTAL kernel, overriding "
                         "--match-impl (pallas_fused: match + in-VMEM counts "
-                        "in one kernel, measured 0.083x vs xla on TPU — "
-                        "logged loudly at run time; bench/research only)")
+                        "in one kernel, 0.08x of xla in the step on the TPU "
+                        "in round 5 (DESIGN §8); runs in no benchmark cell; "
+                        "logged loudly at run time)")
     p.add_argument("--counts-impl", choices=["scatter", "matmul", "reduce"],
                    default="scatter",
-                   help="exact-counts formulation (bench_suite.py stage "
-                        "prices them; all bit-identical)")
+                   help="exact-counts formulation (all bit-identical; "
+                        "matmul and reduce run in no benchmark cell, matmul "
+                        "lost in the step on the TPU in round 5, DESIGN §8)")
     p.add_argument("--update-impl", choices=["scatter", "sorted"],
                    default="scatter",
                    help="register-update formulation (DESIGN §15): scatter "
@@ -1500,7 +1503,7 @@ def make_parser() -> argparse.ArgumentParser:
                         "over the sorted runs (the MapReduce-combiner "
                         "sort half; weight-linear, composes with "
                         "--coalesce).  Reports are bit-identical; "
-                        "bench_suite.py stepvariants prices both")
+                        "sorted runs in no benchmark cell")
     p.add_argument("--topk-every", type=int, default=1, metavar="N",
                    help="run talker candidate SELECTION every Nth chunk "
                         "only (the talker sketch still absorbs every "

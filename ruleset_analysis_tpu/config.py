@@ -623,15 +623,16 @@ class AnalysisConfig:
     #: "pallas" (explicit-layout TPU kernel, ops/pallas_match.py), or
     #: "pallas_fused" (match + in-VMEM count histograms in one kernel,
     #: ops/pallas_fused.py — replaces the batch-sized exact-counts scatter
-    #: with a row-sized one).  ``bench_suite.py pallas`` compares all
-    #: three on the deployment hardware.
+    #: with a row-sized one).  Only "xla" runs in a benchmark cell; on
+    #: the TPU in round 5 "pallas" ran at 0.26x/0.15x of it (DESIGN §3)
+    #: and "pallas_fused" at 0.08x in the step (DESIGN §8).
     match_impl: str = "xla"
     #: Exact-counts formulation: "scatter" (segment-sum scatter-add,
     #: default), "matmul" (one-hot matmul on the MXU), or "reduce"
     #: (compare-and-reduce on the VPU).  All bit-identical
-    #: (ops/counts.py); ``bench_suite.py stage`` prices them on the
-    #: deployment hardware — the TPU trace shows the scatter at 9.2 ms of
-    #: a 60 ms step, so flipping this is a measured-default candidate.
+    #: (ops/counts.py).  Only "scatter" runs in a benchmark cell;
+    #: "matmul" ran at 0.75x of it in the step on the TPU in round 5
+    #: (DESIGN §8).
     counts_impl: str = "scatter"
     #: Register-update formulation (DESIGN §15): "scatter" (five
     #: batch-sized scatter-add/scatter-max updates per step — the
@@ -639,9 +640,9 @@ class AnalysisConfig:
     #: with lax.sort, then segment-sum / segment-max over the sorted
     #: runs — the MapReduce-combiner sort half, ops/sorted_update.py).
     #: Bit-identical reports either way (uint32 add/max associativity);
-    #: ``bench_suite.py stepvariants`` prices both on the deployment
-    #: hardware.  Composes with counts_impl (matmul/reduce counts are
-    #: already scatter-free and keep their formulation) and with
+    #: only "scatter" runs in a benchmark cell.  Composes with
+    #: counts_impl (matmul/reduce counts are already scatter-free and
+    #: keep their formulation) and with
     #: coalesced/weighted inputs (the sorted updates are weight-linear
     #: by construction).
     update_impl: str = "scatter"

@@ -442,7 +442,7 @@ class NativePacker:
         this call follows the same side-channel contract instead of the
         old loud v4-only refusal).  Callers that never drain
         :meth:`take_v6` on a unified corpus would accumulate staged rows
-        — the historical reason for the refusal (ADVICE r5 #2) — so
+        — the historical reason for the refusal — so
         prefer :meth:`pack_lines2` when v6 traffic is possible.
         """
         return self._pack_lines_v4(lines, batch_size)

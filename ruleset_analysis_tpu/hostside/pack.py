@@ -990,7 +990,7 @@ def save_packed(packed: PackedRuleset, path_prefix: str) -> None:
 #: (lo, hi, name) column pairs that every rule row must keep ordered.  The
 #: device predicate is the branch-free wraparound check (x - lo) <= (hi - lo)
 #: on uint32, which assumes lo <= hi: an inverted pair would silently match
-#: almost every value instead of matching nothing (ADVICE r4, medium).
+#: almost every value instead of matching nothing.
 _RANGE_COLS = (
     (R_PLO, R_PHI, "proto"),
     (R_SLO, R_SHI, "src"),

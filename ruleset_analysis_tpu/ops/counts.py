@@ -60,8 +60,8 @@ def segment_counts_reduce(
 
     XLA fuses the compare into the reduction (reductions accept fused
     producers, dots do not), so nothing [B, K]-shaped materializes; all
-    VPU, no scatter, no MXU.  ``bench_suite.py stage`` measures all three
-    formulations; ``AnalysisConfig.counts_impl`` selects per deployment.
+    VPU, no scatter, no MXU.  ``AnalysisConfig.counts_impl`` selects the
+    formulation; only the scatter runs in a benchmark cell.
     """
     with jax.named_scope("ra.counts"):
         iota = jnp.arange(n_keys, dtype=_U32)

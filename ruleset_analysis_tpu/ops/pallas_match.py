@@ -15,8 +15,8 @@ the compiler's discretion.  This kernel pins the layout explicitly:
 
 Use :func:`first_match_rows_pallas` as a drop-in for
 ``ops.match.first_match_rows``; ``tests/test_pallas_match.py`` pins
-equality (interpret mode on CPU, compiled on TPU) and ``bench_suite.py
-pallas`` compares throughput.  The r5 compiled A/B measured this kernel
+equality (interpret mode on CPU, compiled on TPU).  It runs in no
+benchmark cell.  The round-5 compiled A/B on the TPU measured this kernel
 at 0.15-0.26x the XLA-fused predicate (the [N,1] field layout wastes
 VMEM 128x, forcing small grid blocks, while XLA tiles the same
 compare-reduce freely) — "xla" stays the default BY MEASUREMENT; select

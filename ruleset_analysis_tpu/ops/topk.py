@@ -190,7 +190,7 @@ def select_candidates(talk_cms, acl, src, valid, k, slots: int = CAND_SLOTS,
     """
     # A per-shard batch smaller than the stride would leave bs == 0 and
     # feed ZERO candidates every chunk — an empty talker report with no
-    # warning (ADVICE r4).  Degrade to exact full-batch selection instead;
+    # warning.  Degrade to exact full-batch selection instead;
     # shapes are static so this resolves at trace time.
     with jax.named_scope("ra.topk"):
         acl, src, valid = sample_cols(acl, src, valid, salt, sample_shift)

@@ -416,9 +416,10 @@ CORES = {
 
 #: Bake the rule tensor into the compiled step as an XLA constant when it
 #: is at most this many bytes.  The ruleset is fixed for a whole stream,
-#: and constant rules let XLA specialize the [B, R] predicate evaluation —
-#: measured ~2x the whole fused step vs passing rules as a traced argument
-#: (bench_suite.py stage).  Above the threshold the generic argument path
+#: and constant rules let XLA specialize the [B, R] predicate evaluation.
+#: In the step on the TPU in round 5 the two paths measured 1.00x (DESIGN
+#: §8); every benchmark cell runs this one, and the generic argument path
+#: none.  Above the threshold the generic argument path
 #: keeps compile time and HLO size bounded for pathological rulesets.
 RULES_CONST_MAX_BYTES = 8 << 20
 
@@ -564,7 +565,7 @@ def _cached_step(
     the same (mesh, config geometry) in the same process gets the SAME
     step closure back — and therefore hits the jit executable cache
     instead of re-tracing and re-compiling.  This is what makes the
-    warm-run-then-measure pattern (bench.py/bench_suite.py) actually
+    warm-up passes of ``benchmark/`` and ``chip_smoke.py`` actually
     measure steady state: a fresh closure per run would recompile even
     with identical shapes.  Keyed values are all hashable scalars plus
     the Mesh (hashable by devices + axis names); maxsize bounds the

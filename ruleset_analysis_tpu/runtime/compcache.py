@@ -2,7 +2,7 @@
 
 The analysis step compiles once per (mesh, batch geometry, sketch
 geometry); a fresh process pays that compile again unless the persistent
-cache is on.  Entry points (CLI, bench.py, bench_suite.py, chip_smoke.py)
+cache is on.  Entry points (the CLI, ``chip_smoke.py``, ``benchmark/``)
 call :func:`enable_persistent_cache` before first compile; libraries never
 touch global JAX config themselves.
 

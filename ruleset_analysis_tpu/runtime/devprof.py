@@ -302,9 +302,9 @@ class DevprofCapture:
     profiler arms before dispatch warmup+1 and disarms after dispatch
     warmup+steps completes (output synced first — async backends must
     not close the window with work in flight).  Everything after is a
-    plain pass-through, so a long run pays the capture cost once and
-    the sustained rate barely moves (bench_suite ``steptrace`` pins the
-    armed/disarmed ratio >= 0.98).
+    plain pass-through, so a long run pays the capture cost once
+    (its cost on the chip is not measured; reports are bit-identical
+    armed or disarmed, ``tests/test_devprof.py``).
     """
 
     def __init__(self, out_dir: str, steps: int = 16, warmup: int = 3,
@@ -327,7 +327,7 @@ class DevprofCapture:
         #: iteration emits a thunk event, so a profiled step can run
         #: 10-50x slower than a plain one.  The pause is priced apart
         #: from the run's sustained rate the same way compile is
-        #: (bench_suite steptrace; DESIGN §14).
+        #: (DESIGN §14).
         self._window_wall: float | None = None
         self._t_window0: float | None = None
         #: label -> {"fn", "args_sds", "dispatches"} (programs seen in-window)

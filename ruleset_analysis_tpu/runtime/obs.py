@@ -9,8 +9,9 @@ across parse/pack/H2D/step/checkpoint and *what every worker was doing*
 at any fault or re-formation.
 
 Three pieces, one arming discipline (the ``faults.py`` pattern — the
-disarmed cost of every site is a single module-global ``None`` check,
-verified by ``bench_suite.py obs``):
+disarmed cost of every site is a single module-global ``None`` check;
+``tests/test_obs.py::test_disarmed_everything_is_noop`` pins that a
+disarmed site writes nothing):
 
 - **Span tracer.**  :func:`complete`/:func:`span`/:func:`instant` record
   Chrome trace-event spans (loads in Perfetto / ``chrome://tracing``).

@@ -165,7 +165,7 @@ class _TextSource:
     A batch whose raw lines produced NO v4 tuple rows (a mostly-IPv6 or
     mostly-unparseable stretch of the corpus) is yielded as ``(None,
     n_raw)``: the driver accounts the raw lines (and drains any staged v6
-    rows) without stepping an all-invalid device chunk — ADVICE r5 #3.
+    rows) without stepping an all-invalid device chunk.
     """
 
     def __init__(self, packed: PackedRuleset, lines: Iterable[str]):
@@ -1623,7 +1623,7 @@ def run_stream_file_distributed(
             return report, pipeline.state_to_host(state)
         return report
     finally:
-        # release the wire mmaps deterministically (ADVICE r4): a
+        # release the wire mmaps deterministically: a
         # long-lived driver iterating many wire inputs must not wait
         # for GC to drop file mappings
         close = getattr(source, "close", None)
@@ -1772,7 +1772,7 @@ def _run_core(
     Sources holding OS resources (the wire reader's mmaps, the ingest
     pipeline's producer threads) expose ``close()``; releasing them here
     instead of at GC time keeps repeated wire runs in one process from
-    accumulating file mappings (ADVICE r4) and never strands a prefetch
+    accumulating file mappings and never strands a prefetch
     producer on a full queue.
 
     Pipelined ingest (``cfg.prefetch_depth > 0``, runtime/ingest.py)

@@ -457,8 +457,7 @@ def test_trace_diff_cli_on_real_captures(corpus, tmp_path):
     """Two real captures (counts scatter vs reduce) diff end to end.
 
     ``slow``: two full captures (each re-lowers + compiles the step for
-    attribution) on top of the synthetic-diff coverage above; the
-    committed DEVPROF_r12_cpu.json artifact exercises the same path.
+    attribution) on top of the synthetic-diff coverage above.
     """
     packed, _prefix, _log, wirep = corpus
     outs = {}

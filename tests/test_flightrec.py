@@ -42,6 +42,7 @@ from ruleset_analysis_tpu.runtime.flightrec import (
 from ruleset_analysis_tpu.runtime.metrics import (
     LATENCY_BUCKET_BOUNDS, LatencyHistogram, quantile_from_prom,
 )
+from ruleset_analysis_tpu.runtime.report import VOLATILE_TOTALS
 
 from test_serve import finish, get_json, start_serve, wait_for
 
@@ -331,6 +332,28 @@ def test_clean_exit_leaves_no_forensics(corpus, tmp_path):
     assert run_cli(prefix, log, bb) == 0
     # no shards, no postmortem — an unarmed-trace clean run is untouched
     assert not os.path.exists(str(bb)) or not os.listdir(str(bb))
+
+
+def test_armed_recorder_report_bit_identical_to_off(corpus, tmp_path):
+    """The recorder only watches: a run with it armed writes the same
+    report as a ``--blackbox off`` run, volatile totals aside."""
+    _, prefix, log, _ = corpus
+    images = []
+    for name, flags in (
+        ("on", ["--blackbox-dir", str(tmp_path / "bb-on")]),
+        ("off", ["--blackbox", "off"]),
+    ):
+        out = tmp_path / f"{name}.json"
+        assert cli.main([
+            "run", "--ruleset", prefix, "--logs", log, *RUN_FLAGS,
+            "--json", "--out", str(out), *flags,
+        ]) == 0
+        flightrec._reset_for_tests()  # arming is per CLI invocation
+        rep = json.loads(out.read_text())
+        for k in VOLATILE_TOTALS:
+            rep["totals"].pop(k, None)
+        images.append(rep)
+    assert images[0] == images[1]
 
 
 def test_stall_bundle_attributes_the_starved_side(corpus, tmp_path, capsys):

@@ -434,6 +434,48 @@ def test_serve_lineage_e2e_ledger_routes_and_slo_gauges(corpus, tmp_path):
     assert "trend_events" not in diff
 
 
+def test_serve_slo_breach_names_drops_then_recovers(corpus, tmp_path):
+    """A live window with 200 listener drops under ``drop_rate<=0.001``
+    breaches once, its lineage record carries the 200 drops, and three
+    clean windows bring exactly one recovery."""
+    packed, prefix, lines, td = corpus
+    scfg = ServeConfig(
+        listen=("tcp:127.0.0.1:0",), window_lines=WL, ring=8,
+        serve_dir=str(tmp_path / "serve"), max_windows=0, stop_after_sec=120,
+        reload_watch=False, checkpoint_every_windows=0, http="off",
+        queue_lines=10_000, slo="drop_rate<=0.001",
+    )
+    drv, th, out = start_serve(prefix, AnalysisConfig(**RUN_CFG), scfg)
+
+    def observed(n):
+        wait_for(lambda: out.get("error") or drv.slo.windows_observed >= n,
+                 msg=f"{n} windows observed")
+        if "error" in out:
+            raise out["error"]
+
+    try:
+        addr = tuple(drv.listeners.listeners[0].address)
+        send_tcp(addr, lines[:WL])
+        observed(1)
+        assert drv.slo.breaches_total == 0
+        # 200 lines dropped, WL more accepted: window 1 closes lossy
+        with faults.armed(faults.FaultPlan.parse("listener.drop@1:200")):
+            send_tcp(addr, lines[WL:2 * WL + 200])
+            observed(2)
+        assert drv.slo.breaches_total == 1, drv.slo.gauges()
+        assert drv.slo.gauges()["slo_breached"] == 1
+        assert drv.lineage_record(1)["hosts"][0]["drops"] == 200
+        for w in range(3):
+            send_tcp(addr, lines[:WL])
+            observed(3 + w)
+        assert drv.slo.recoveries_total == 1, drv.slo.gauges()
+        assert drv.slo.gauges()["slo_breached"] == 0
+        assert drv.slo.breaches_total == 1
+    finally:
+        drv.stop()
+    finish(th, out)
+
+
 def test_serve_lineage_disarmed_has_no_plane(corpus, tmp_path):
     packed, prefix, lines, td = corpus
     scfg = ServeConfig(

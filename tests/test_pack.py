@@ -126,7 +126,7 @@ def test_load_packed_rejects_inverted_range(tmp_path):
 
     Under the wraparound predicate (x - lo) <= (hi - lo) an inverted
     range matches nearly everything, silently inflating that rule's hits
-    and hiding it from the unused set (ADVICE r4, medium) — so load
+    and hiding it from the unused set — so load
     refuses the matrix instead of shipping it.
     """
     import pytest

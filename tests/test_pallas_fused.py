@@ -5,7 +5,7 @@ exact-counts scatter with in-VMEM histograms + a row-sized fold.  These
 tests pin its keys AND counts delta bit-identical to
 ``segment_counts(match_keys(...), valid)`` — including no-match lines
 (implicit deny), invalid lines, lane/batch padding, and the full stream
-driver.  Interpret mode on CPU; compiled on TPU (bench_suite.py pallas).
+driver.  Interpret mode on CPU.  The kernel runs in no benchmark cell.
 """
 
 import numpy as np

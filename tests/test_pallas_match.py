@@ -1,7 +1,8 @@
 """Pallas first-match kernel: bit-equality with the XLA-fused path.
 
 Runs in pallas interpret mode on the CPU test mesh (the kernel compiles
-natively on TPU; bench_suite.py compares the two there).
+natively on TPU: ``chip_smoke.py`` runs it there against the oracle,
+``tests/test_tpu_compile.py`` compiles it for a described v5e).
 """
 
 import numpy as np

@@ -327,7 +327,7 @@ def test_talker_sampled_phase_rotates_with_salt():
 def test_talker_sampled_selection_small_batch_degrades_to_exact():
     """A per-shard batch smaller than 2**shift must fall back to exact
     full-batch selection — not silently select zero candidates every
-    chunk (ADVICE r4)."""
+    chunk."""
     b = 4  # < 2**3
     src = np.asarray([7, 7, 7, 9], dtype=np.uint32)
     acl = np.zeros(b, dtype=np.uint32)

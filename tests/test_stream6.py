@@ -188,6 +188,33 @@ def test_synth_unified_corpus_end_to_end():
     assert rep.totals["lines_matched"] == res.lines_matched
 
 
+def test_mixed_sketch_only_unused_recall():
+    """Sketch-only registers (no exact counts) on an interleaved v4+v6
+    stream: the CMS holds both families' hits, so the unused set keeps
+    >= 99% of the exact one and never names a rule that was hit."""
+    from ruleset_analysis_tpu.hostside import synth
+
+    cfg_text = synth.synth_config(
+        n_acls=4, rules_per_acl=64, seed=41, v6_fraction=0.4
+    )
+    rs = aclparse.parse_asa_config(cfg_text, "fw1")
+    packed = pack.pack_rulesets([rs])
+    t4 = synth.synth_tuples(packed, 3000, seed=41)
+    t6 = synth.synth_tuples6(packed, 2000, seed=41)
+    lines = synth.render_syslog(packed, t4, seed=41) + synth.render_syslog6(
+        packed, t6, seed=42
+    )
+    random.Random(9).shuffle(lines)
+    exact_unused = oracle.Oracle([rs]).consume(list(lines)).unused_rules([rs])
+    cfg = AnalysisConfig(
+        backend="tpu", batch_size=256, exact_counts=False,
+        sketch=SketchConfig(cms_width=1 << 10, cms_depth=4, hll_p=8),
+    )
+    rep = run_stream(packed, iter(lines), cfg, topk=5)
+    assert oracle.unused_rule_recall(exact_unused, rep.unused) >= 0.99
+    assert set(rep.unused) <= set(exact_unused)
+
+
 @pytest.mark.parametrize("seed", [2, 8])
 def test_native_python_v6_differential(seed):
     """Python LinePacker vs native parser: bit-identical dual-family packs."""
@@ -496,7 +523,7 @@ def test_106023_src_mid_token_colon_backtracks_like_regex():
 def test_zero_valid_v4_batches_skip_device_step():
     """A mostly-IPv6 corpus must not step all-invalid v4 device chunks:
     _TextSource yields (None, n_raw) for zero-fill batches and the driver
-    accounts the raw lines without a v4 step (ADVICE r5 #3)."""
+    accounts the raw lines without a v4 step."""
     from ruleset_analysis_tpu.runtime.stream import _TextSource
 
     rs = aclparse.parse_asa_config(CFG, "fw1")

@@ -443,8 +443,7 @@ def test_cli_topk_sample_shift_plumbing(corpus, tmp_path, capsys):
 
 def test_wire_run_closes_reader_deterministically(corpus, wire_path, monkeypatch):
     """run_stream_wire must release the reader's mmaps in a finally —
-    long-lived drivers over many wire inputs cannot wait for GC
-    (ADVICE r4)."""
+    long-lived drivers over many wire inputs cannot wait for GC."""
     closed = []
     orig = wire.WireReader.close
 

@@ -41,9 +41,7 @@ answers the attribution question directly from the timeline:
   giveups, from the ``retry.attempt``/``retry.recovered``/
   ``retry.giveup`` instants the policy engine emits.
 
-``bench_suite.py obs`` imports :func:`summarize` to record stage
-attribution in its artifact; tests assert the merged traces of chaos
-runs stay summarizable.
+Tests assert the merged traces of chaos runs stay summarizable.
 """
 
 from __future__ import annotations
