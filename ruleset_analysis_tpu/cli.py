@@ -1481,8 +1481,10 @@ def make_parser() -> argparse.ArgumentParser:
                    help="per-ACL lane width for --layout=stacked (0 = auto)")
     p.add_argument("--match-impl", choices=["xla", "pallas"],
                    default="xla",
-                   help="first-match kernel (pallas runs in no benchmark cell; it "
-                        "lost to xla on the TPU in round 5, DESIGN §3)")
+                   help="first-match kernel: xla runs the backend's scan (the "
+                        "lane-dense Pallas kernel on the TPU, the XLA predicate "
+                        "elsewhere, DESIGN §3); pallas forces the kernel "
+                        "(interpreted off the TPU)")
     p.add_argument("--experimental-match-impl", choices=["pallas_fused"],
                    default=None, metavar="IMPL",
                    help="enable an EXPERIMENTAL kernel, overriding "

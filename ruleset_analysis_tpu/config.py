@@ -619,13 +619,14 @@ class AnalysisConfig:
     resume: bool = False  # resume from checkpoint_dir if a snapshot exists
     report_every_chunks: int = 0  # 0 = no periodic throughput lines on stderr
     seed: int = 0
-    #: First-match kernel implementation: "xla" (fused predicate, default),
-    #: "pallas" (explicit-layout TPU kernel, ops/pallas_match.py), or
+    #: First-match kernel implementation: "xla" (default: the backend's
+    #: scan — on the TPU the lane-dense Pallas kernel, ops/pallas_match.py,
+    #: elsewhere the XLA predicate, ops/match.py; DESIGN §3), "pallas"
+    #: (the lane-dense kernel on any backend, interpreted off the TPU), or
     #: "pallas_fused" (match + in-VMEM count histograms in one kernel,
     #: ops/pallas_fused.py — replaces the batch-sized exact-counts scatter
-    #: with a row-sized one).  Only "xla" runs in a benchmark cell; on
-    #: the TPU in round 5 "pallas" ran at 0.26x/0.15x of it (DESIGN §3)
-    #: and "pallas_fused" at 0.08x in the step (DESIGN §8).
+    #: with a row-sized one; 0.08x of the XLA step on the TPU in round 5,
+    #: DESIGN §8).  Every benchmark cell runs the default.
     match_impl: str = "xla"
     #: Exact-counts formulation: "scatter" (segment-sum scatter-add,
     #: default), "matmul" (one-hot matmul on the MXU), or "reduce"

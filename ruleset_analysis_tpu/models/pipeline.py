@@ -34,6 +34,7 @@ from ..hostside.pack import (
 from ..ops import cms as cms_ops
 from ..ops import counts as count_ops
 from ..ops import hll as hll_ops
+from ..ops import match as match_ops
 from ..ops import topk as topk_ops
 from ..ops.match import RULE_BLOCK, match_keys, match_keys_stacked
 
@@ -45,8 +46,8 @@ class DeviceRuleset(NamedTuple):
 
     rules: jax.Array  # [R, RULE_COLS] uint32, R % rule_block == 0
     deny_key: jax.Array  # [n_acls] uint32
-    #: field-major lane-padded twin for the pallas kernel; None on the
-    #: default XLA path (ship_ruleset(match_impl="pallas") fills it)
+    #: field-major lane-padded twin for the fused pallas kernel; None
+    #: elsewhere (ship_ruleset(match_impl="pallas_fused") fills it)
     rules_fm: jax.Array | None = None
 
 
@@ -236,10 +237,10 @@ def ship_ruleset(
     # pallas_fused is an explicit experimental surface (VERDICT r5 Weak
     # #4: 0.083x vs XLA); the loud warning lives in the step builder
     # (parallel/step.py), which every driver path crosses exactly once
-    if match_impl in ("pallas", "pallas_fused"):
-        from ..ops import pallas_match
+    if match_impl == "pallas_fused":
+        from ..ops import pallas_fused
 
-        rules_fm = pallas_match.prep_rules(rules)
+        rules_fm = pallas_fused.prep_rules(rules)
     return DeviceRuleset(
         rules=rules,
         deny_key=jnp.asarray(packed.deny_key.astype(np.uint32)),
@@ -329,6 +330,41 @@ def ship_ruleset_host(packed: PackedRuleset, rule_block: int = RULE_BLOCK) -> De
         deny_key=packed.deny_key.astype(np.uint32),
         rules_fm=None,
     )
+
+
+def flat_match(
+    cols: dict,
+    valid: jax.Array,
+    ruleset: DeviceRuleset,
+    *,
+    match_impl: str,
+    rule_block: int,
+    n_keys: int,
+) -> tuple[jax.Array, jax.Array | None]:
+    """Count keys of the flat v4 step, and the fused kernel's counts delta.
+
+    The backend picks the scan: on the TPU the lane-dense Pallas kernel
+    (ops/pallas_match.py), elsewhere the XLA predicate (ops/match.py),
+    as pallas_call has no compiled CPU lowering.  ``match_impl="pallas"``
+    forces the kernel on any backend (interpreted off the TPU);
+    ``"pallas_fused"`` with a shipped ``rules_fm`` also returns the
+    in-VMEM counts delta.  Shared by :func:`analysis_step` and the
+    sharded step (parallel/step.py ``_core_flat``).
+    """
+    if match_impl == "pallas_fused" and ruleset.rules_fm is not None:
+        from ..ops import pallas_fused
+
+        return pallas_fused.match_keys_and_counts_pallas(
+            cols, valid, ruleset.rules, ruleset.rules_fm, ruleset.deny_key,
+            n_keys,
+        )
+    if match_impl == "pallas" or match_ops.tpu_backend():
+        from ..ops import pallas_match
+
+        return pallas_match.match_keys_pallas(
+            cols, ruleset.rules, ruleset.deny_key
+        ), None
+    return match_keys(cols, ruleset.rules, ruleset.deny_key, rule_block), None
 
 
 def _update_registers(
@@ -454,22 +490,10 @@ def analysis_step(
     ``[WIRE_COLS, B]`` layout (see :func:`batch_cols`).
     """
     cols, valid = batch_cols(batch)
-    counts_delta = None
-    if match_impl == "pallas_fused" and ruleset.rules_fm is not None:
-        from ..ops import pallas_fused
-
-        keys, counts_delta = pallas_fused.match_keys_and_counts_pallas(
-            cols, valid, ruleset.rules, ruleset.rules_fm, ruleset.deny_key,
-            n_keys,
-        )
-    elif match_impl == "pallas" and ruleset.rules_fm is not None:
-        from ..ops import pallas_match
-
-        keys = pallas_match.match_keys_pallas(
-            cols, ruleset.rules, ruleset.rules_fm, ruleset.deny_key
-        )
-    else:
-        keys = match_keys(cols, ruleset.rules, ruleset.deny_key, rule_block)
+    keys, counts_delta = flat_match(
+        cols, valid, ruleset, match_impl=match_impl, rule_block=rule_block,
+        n_keys=n_keys,
+    )
     return _update_registers(
         state, keys, valid, cols["src"], cols["acl"],
         n_keys=n_keys, topk_k=topk_k, exact_counts=exact_counts, salt=salt,

@@ -74,6 +74,16 @@ def _block_min_row(cols: dict, rules: jnp.ndarray, base: jnp.ndarray) -> jnp.nda
     return jnp.min(jnp.where(ok, idx, NO_MATCH), axis=1)
 
 
+def tpu_backend() -> bool:
+    """Whether the program compiles for a TPU.
+
+    The flat v4 step then runs the lane-dense Pallas scan
+    (ops/pallas_match.py); other backends keep the XLA predicate below,
+    since ``pallas_call`` has no compiled CPU lowering.
+    """
+    return jax.default_backend() == "tpu"
+
+
 # numpy scalar, NOT jnp: a module-level jnp scalar would initialize the
 # JAX backend at import time, taking the chip in every process that
 # merely imports this module; np.uint32 participates in jnp expressions

@@ -40,18 +40,99 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from ..hostside.pack import R_KEY, RULE_COLS
-from .match import rows_to_keys
-from .pallas_match import (  # noqa: F401
-    BLOCK_LINES,
-    RULE_TILE,
-    _ceil_to,
-    prep_rules,
-    tile_first_match,
+from ..hostside.pack import (
+    R_ACL,
+    R_DHI,
+    R_DLO,
+    R_DPHI,
+    R_DPLO,
+    R_KEY,
+    R_PHI,
+    R_PLO,
+    R_SHI,
+    R_SLO,
+    R_SPHI,
+    R_SPLO,
+    RULE_COLS,
 )
+from .match import NO_MATCH, rows_to_keys
 
 _U32 = jnp.uint32
+_I32 = jnp.int32
 _NO_MATCH = 0xFFFFFFFF
+#: int32 in-kernel sentinel: Mosaic TPU has no lowering for reductions
+#: over UNSIGNED integers (r5 TPU window), so the running min over rule
+#: tiles is carried in int32 — row indices are far below 2^31 — and
+#: mapped back to the uint32 NO_MATCH at the kernel boundary.
+_NO_MATCH_I32 = 0x7FFFFFFF
+
+#: Lines per grid step (sublane-major).  A [BLOCK_LINES, 1] u32 block is
+#: physically tiled (8, 128), so it occupies BLOCK_LINES x 128 lanes of
+#: VMEM — 512 KB at 1024 lines; 4096 OOM'd at 28 MB on the first real-TPU
+#: compile (r5 window), 1024 leaves ~2x headroom.
+BLOCK_LINES = 1024
+
+#: Rules per lane tile — the VPU lane width.
+RULE_TILE = 128
+
+
+def tile_first_match(fields: tuple, rules, n_tiles: int):
+    """Min matching global row per line, over rule tiles on lanes.
+
+    ``fields`` = (acl, proto, src, sport, dst, dport) as [BLOCK_LINES, 1]
+    u32 VALUES (lines on sublanes); ``rules`` is the [RULE_COLS, Rp]
+    field-major ref.  Returns the [BLOCK_LINES, 1] running-min rows.
+    """
+    a, p, s, sp, d, dp = fields
+
+    def body(t, best):
+        sl = pl.ds(t * RULE_TILE, RULE_TILE)
+
+        def row(c):
+            return rules[c, sl][None, :]  # [1, RULE_TILE]
+
+        def in_range(lo_c, hi_c, x):
+            # unsigned wraparound range check, as ops.match
+            lo = row(lo_c)
+            return (x - lo) <= (row(hi_c) - lo)
+
+        ok = (
+            (row(R_ACL) == a)
+            & in_range(R_PLO, R_PHI, p)
+            & in_range(R_SLO, R_SHI, s)
+            & in_range(R_SPLO, R_SPHI, sp)
+            & in_range(R_DLO, R_DHI, d)
+            & in_range(R_DPLO, R_DPHI, dp)
+        )
+        idx = (
+            lax.broadcasted_iota(_I32, (1, RULE_TILE), 1)
+            + (t * RULE_TILE).astype(_I32)
+        )
+        cand = jnp.where(ok, jnp.broadcast_to(idx, ok.shape), _I32(_NO_MATCH_I32))
+        return jnp.minimum(best, jnp.min(cand, axis=1, keepdims=True))
+
+    init = jnp.full((a.shape[0], 1), _NO_MATCH_I32, dtype=_I32)
+    best = lax.fori_loop(0, n_tiles, body, init)
+    return jnp.where(best == _I32(_NO_MATCH_I32), _U32(_NO_MATCH), best.astype(_U32))
+
+
+def prep_rules(rules: jnp.ndarray) -> jnp.ndarray:
+    """[R, RULE_COLS] row-major -> [RULE_COLS, Rp] field-major, lane-padded.
+
+    Padding columns carry NO_MATCH in the ACL field so they never match
+    (mirrors pack.py's NO_ACL padding rows).
+    """
+    r = rules.shape[0]
+    rp = _ceil_to(r, RULE_TILE)
+    t = jnp.transpose(rules.astype(_U32))  # [RULE_COLS, R]
+    if rp != r:
+        pad = jnp.zeros((RULE_COLS, rp - r), dtype=_U32).at[R_ACL].set(NO_MATCH)
+        t = jnp.concatenate([t, pad], axis=1)
+    return t
+
+
+def _ceil_to(n: int, m: int) -> int:
+    return ((n + m - 1) // m) * m
 
 
 def _kernel(

@@ -1,27 +1,33 @@
-"""Pallas TPU kernel for the first-match scan (alternative to ops.match).
+"""Pallas TPU kernel for the v4 first-match scan, with lines on lanes.
 
-The XLA-fused predicate (ops/match.py) keeps the VPU reasonably busy,
-but it re-decides tiling per shape and materializes block temporaries at
-the compiler's discretion.  This kernel pins the layout explicitly:
+The XLA predicate (ops/match.py) evaluates a ``[B, Rb]`` block with lines
+on sublanes and rules on lanes: every line value is broadcast across the
+128 lanes, and each line's first match is a cross-lane min, both relayout
+work on the cross-lane unit for every rule block.  This kernel takes the
+transpose:
 
-- line fields live along SUBLANES ([BLOCK_LINES, 1] per field), rule
-  fields along LANES ([1, 128] per rule tile), so one VPU op evaluates
-  128 rules for 8 lines;
-- the whole (transposed, lane-padded) rule tensor stays resident in
-  VMEM across the batch grid; the running min over rule tiles is a
-  register carry in a ``fori_loop`` — nothing [B, R]-shaped ever exists;
-- first-match == min matching global rule index, as in ops.match
-  (pack.py emits rows in config order — the parity-critical invariant).
+- each field of a block of lines is ``[rows, 128]`` u32 with lines along
+  LANES, so one vreg holds 1,024 lines of one field, and a grid block is
+  lane-dense (no 128x VMEM padding of ``[N, 1]`` columns);
+- each rule's ``acl``, ``lo`` and ``hi - lo`` (five ranges) are SCALARS,
+  read from a rule block staged into SMEM (rule-major, one 16-word record
+  a rule) on the grid's inner, sequential axis, and applied to whole line
+  vregs: no line value is ever broadcast;
+- rows are scanned in DESCENDING order and each line keeps
+  ``best = select(ok, row, best)`` in the revisited output block, so the
+  last writer is the smallest matching row: the first match, with no
+  cross-lane reduction and no (unsigned) min at all.
 
-Use :func:`first_match_rows_pallas` as a drop-in for
-``ops.match.first_match_rows``; ``tests/test_pallas_match.py`` pins
-equality (interpret mode on CPU, compiled on TPU).  It runs in no
-benchmark cell.  The round-5 compiled A/B on the TPU measured this kernel
-at 0.15-0.26x the XLA-fused predicate (the [N,1] field layout wastes
-VMEM 128x, forcing small grid blocks, while XLA tiles the same
-compare-reduce freely) — "xla" stays the default BY MEASUREMENT; select
-with ``AnalysisConfig(match_impl="pallas")`` where a different balance
-holds.
+Every (line, row) pair is still tested, as in ops.match: pack.py emits
+rows in configuration order, and padding rows carry ``NO_ACL``.  The
+result is bit-identical to ``ops.match.first_match_rows``
+(``tests/test_pallas_match.py``, interpret mode on the CPU).
+
+The flat v4 step runs this kernel on the TPU backend
+(``models.pipeline.flat_match``); other backends keep the XLA predicate,
+since ``pallas_call`` has no compiled CPU lowering.
+``AnalysisConfig(match_impl="pallas")`` forces the kernel on any backend
+(interpreted off the TPU).
 """
 
 from __future__ import annotations
@@ -32,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..hostside.pack import (
     R_ACL,
@@ -45,173 +52,174 @@ from ..hostside.pack import (
     R_SLO,
     R_SPHI,
     R_SPLO,
-    RULE_COLS,
+    RULE_BLOCK,
 )
-from .match import NO_MATCH
+from . import match as _match
+from .match import rows_to_keys
 
 _U32 = jnp.uint32
-_I32 = jnp.int32
-#: Python-int twin of ops.match.NO_MATCH — pallas kernels cannot capture
-#: module-level jax arrays, only literals.
+#: Python-int twin of ops.match.NO_MATCH (kernels capture literals only)
 _NO_MATCH = 0xFFFFFFFF
-#: int32 in-kernel sentinel: Mosaic TPU has no lowering for reductions
-#: over UNSIGNED integers (first compiled run, r5 TPU window:
-#: "NotImplementedError: Reductions over unsigned integers"), so the
-#: running-min over rule tiles is carried in int32 — row indices are far
-#: below 2^31 — and mapped back to the uint32 NO_MATCH at the kernel
-#: boundary, keeping callers bit-compatible with ops.match.
-_NO_MATCH_I32 = 0x7FFFFFFF
 
-#: Lines per grid step (sublane-major).  A [BLOCK_LINES, 1] u32 block is
-#: physically tiled (8, 128), so it occupies BLOCK_LINES x 128 lanes of
-#: VMEM — 512 KB at 1024 lines.  Seven such blocks (6 in + 1 out), double-
-#: buffered across the grid, plus the [BLOCK_LINES, RULE_TILE] compare
-#: temporary must fit the 16 MB scoped-vmem limit; 4096 OOM'd at 28 MB on
-#: the first real-TPU compile (r5 window), 1024 leaves ~2x headroom.
-BLOCK_LINES = 1024
+#: Lines per vreg row: the lane width.
+LANES = 128
+#: Sublane rows of one sub-tile: 4 vregs of each field, so one rule's 11
+#: scalar reads are spread over 4 line vregs per field and the six fields
+#: plus the running best stay in registers.
+TILE_ROWS = 32
+#: Rows of a grid block along the lines: 16,384 lines, 64 KB a field.
+BLOCK_ROWS = 128
+#: Rules unrolled per loop iteration of the scan.
+UNROLL = 8
 
-#: Rules per lane tile — the VPU lane width.
-RULE_TILE = 128
-
-
-def tile_first_match(fields: tuple, rules, n_tiles: int):
-    """Shared kernel body: min matching global row per line, over rule tiles.
-
-    ``fields`` = (acl, proto, src, sport, dst, dport) as [BLOCK_LINES, 1]
-    u32 VALUES; ``rules`` is the [RULE_COLS, Rp] field-major ref.  The one
-    definition of the tile predicate — ops/pallas_fused.py reuses it, so
-    a predicate change (e.g. a new tuple field) lands in every pallas
-    kernel at once.  Returns the [BLOCK_LINES, 1] running-min rows.
-    """
-    a, p, s, sp, d, dp = fields
-
-    def body(t, best):
-        sl = pl.ds(t * RULE_TILE, RULE_TILE)
-
-        def row(c):
-            return rules[c, sl][None, :]  # [1, RULE_TILE]
-
-        def in_range(lo_c, hi_c, x):
-            # unsigned wraparound range check (see ops.match._block_min_row):
-            # one subtract + one compare per range instead of two compares
-            # + an AND; pack/aclparse + load_packed validation guarantee
-            # lo <= hi
-            lo = row(lo_c)
-            return (x - lo) <= (row(hi_c) - lo)
-
-        ok = (
-            (row(R_ACL) == a)
-            & in_range(R_PLO, R_PHI, p)
-            & in_range(R_SLO, R_SHI, s)
-            & in_range(R_SPLO, R_SPHI, sp)
-            & in_range(R_DLO, R_DHI, d)
-            & in_range(R_DPLO, R_DPHI, dp)
-        )
-        idx = (
-            lax.broadcasted_iota(_I32, (1, RULE_TILE), 1)
-            + (t * RULE_TILE).astype(_I32)
-        )
-        cand = jnp.where(ok, jnp.broadcast_to(idx, ok.shape), _I32(_NO_MATCH_I32))
-        return jnp.minimum(best, jnp.min(cand, axis=1, keepdims=True))
-
-    init = jnp.full((a.shape[0], 1), _NO_MATCH_I32, dtype=_I32)
-    best = lax.fori_loop(0, n_tiles, body, init)
-    return jnp.where(best == _I32(_NO_MATCH_I32), _U32(_NO_MATCH), best.astype(_U32))
+#: Ranges as (lo column, hi column), in the order of the line fields
+#: after ``acl``.
+_RANGES = (
+    (R_PLO, R_PHI),
+    (R_SLO, R_SHI),
+    (R_SPLO, R_SPHI),
+    (R_DLO, R_DHI),
+    (R_DPLO, R_DPHI),
+)
+_FIELDS = ("acl", "proto", "src", "sport", "dst", "dport")
+#: Scalars staged per rule: acl, then lo and hi - lo of each range.
+N_SCALARS = 1 + 2 * len(_RANGES)
+#: Words of a rule's SMEM record: N_SCALARS padded so that a rule block
+#: is a whole number of SMEM's 1,024-word tiles.
+RULE_WORDS = 16
 
 
-def _kernel(acl, proto, src, sport, dst, dport, rules, out, *, n_tiles: int):
-    """One batch block vs every rule tile; running-min carry over tiles.
+def stage_rules(rules: jnp.ndarray) -> jnp.ndarray:
+    """``[R, RULE_COLS]`` -> ``[ceil(R / RULE_BLOCK) * RULE_BLOCK * RULE_WORDS]``.
 
-    Refs: six [BLOCK_LINES, 1] u32 line fields; rules [RULE_COLS, R]
-    u32 (field-major, lane-padded); out [BLOCK_LINES, 1] u32.
-    """
-    out[:] = tile_first_match(
-        (acl[:], proto[:], src[:], sport[:], dst[:], dport[:]),
-        rules, n_tiles,
-    )
-
-
-def prep_rules(rules: jnp.ndarray) -> jnp.ndarray:
-    """[R, RULE_COLS] row-major -> [RULE_COLS, Rp] field-major, lane-padded.
-
-    Padding columns carry NO_MATCH in the ACL field so they never match
-    (mirrors pack.py's NO_ACL padding rows).
+    One record per rule, rule-major: ``acl``, then ``lo`` and ``hi - lo``
+    of each range (the wraparound test ``x - lo <= hi - lo``, as
+    ops.match), then padding.  Rows past R are zeros;
+    :func:`first_match_rows_pallas` discards any match on them.  With the
+    rule tensor baked into the step as a constant, XLA folds this.
     """
     r = rules.shape[0]
-    rp = ((r + RULE_TILE - 1) // RULE_TILE) * RULE_TILE
-    t = jnp.transpose(rules.astype(_U32))  # [RULE_COLS, R]
-    if rp != r:
-        pad = jnp.zeros((RULE_COLS, rp - r), dtype=_U32).at[R_ACL].set(NO_MATCH)
-        t = jnp.concatenate([t, pad], axis=1)
-    return t
+    rp = -(-r // RULE_BLOCK) * RULE_BLOCK
+    rules = rules.astype(_U32)
+    cols = [rules[:, R_ACL]]
+    for lo, hi in _RANGES:
+        cols += [rules[:, lo], rules[:, hi] - rules[:, lo]]
+    t = jnp.stack(cols, axis=1)  # [R, N_SCALARS]
+    t = jnp.pad(t, ((0, rp - r), (0, RULE_WORDS - N_SCALARS)))
+    return t.reshape(rp * RULE_WORDS)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_lines", "interpret")
-)
+def _kernel(rules, acl, proto, src, sport, dst, dport, out, *, n_blocks: int):
+    """One block of lines against one rule block (grid axis 1, descending).
+
+    Refs: ``rules`` [RULE_BLOCK * RULE_WORDS] u32 in SMEM (rule block
+    ``n_blocks - 1 - j``); six ``[BLOCK_ROWS, 128]`` u32 line fields; out
+    ``[BLOCK_ROWS, 128]`` u32, revisited across the rule axis and holding
+    each line's smallest matching row so far.
+    """
+    rb = RULE_BLOCK
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        out[...] = jnp.full(out.shape, _NO_MATCH, _U32)
+
+    base = ((n_blocks - 1 - j) * rb).astype(_U32)
+
+    def tile(t, carry):
+        sl = pl.ds(pl.multiple_of(t * TILE_ROWS, TILE_ROWS), TILE_ROWS)
+        a, *xs = (ref[sl, :] for ref in (acl, proto, src, sport, dst, dport))
+
+        def rules_step(i, best):
+            # rows off + UNROLL - 1 down to off: the dynamic part of each
+            # SMEM address is shared by the UNROLL rules of the step
+            off = pl.multiple_of((rb // UNROLL - 1 - i) * UNROLL, UNROLL)
+            for u in reversed(range(UNROLL)):
+                r = off + u
+                w = r * RULE_WORDS
+                ok = a == rules[w]
+                for k, x in enumerate(xs):
+                    ok = ok & ((x - rules[w + 1 + 2 * k]) <= rules[w + 2 + 2 * k])
+                best = jnp.where(ok, base + r.astype(_U32), best)
+            return best
+
+        out[sl, :] = lax.fori_loop(0, rb // UNROLL, rules_step, out[sl, :])
+        return carry
+
+    lax.fori_loop(0, out.shape[0] // TILE_ROWS, tile, 0)
+
+
 def first_match_rows_pallas(
     cols: dict,
-    rules_fm: jnp.ndarray,  # [RULE_COLS, Rp] from prep_rules
-    block_lines: int = BLOCK_LINES,
+    rules: jnp.ndarray,  # [R, RULE_COLS] row-major (DeviceRuleset.rules)
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Global row index of the first matching ACE per line (pallas path).
+    """Global row index of the first matching ACE per line (lane-dense).
 
-    cols: dict of [B] uint32 arrays (acl/proto/src/sport/dst/dport).
-    Returns [B] u32, NO_MATCH where no rule matches — bit-compatible
-    with ops.match.first_match_rows.  ``interpret=None`` auto-selects:
-    compiled on TPU, the pallas interpreter on the CPU test backend
-    (pallas_call has no compiled CPU lowering).
+    cols: dict of [B] uint32 arrays (acl/proto/src/sport/dst/dport), any
+    B; rules: any R.  Returns [B] u32, NO_MATCH where no rule matches —
+    bit-identical to ops.match.first_match_rows.  ``interpret=None``
+    compiles on the TPU and interprets elsewhere; it is resolved here,
+    outside the jit, so the jit's cache is keyed on the decision.
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    b = cols["acl"].shape[0]
-    rp = rules_fm.shape[1]
-    assert rp % RULE_TILE == 0
-    block_lines = min(block_lines, _ceil_to(b, 8))
-    bp = _ceil_to(b, block_lines)
-
-    def field(name):
-        v = cols[name]
-        if bp != b:  # padded lines produce garbage rows, sliced off below
-            v = jnp.concatenate([v, jnp.zeros(bp - b, dtype=_U32)])
-        return v.reshape(bp, 1)
-
-    line_spec = pl.BlockSpec((block_lines, 1), lambda i: (i, 0))
-    out = pl.pallas_call(
-        functools.partial(_kernel, n_tiles=rp // RULE_TILE),
-        grid=(bp // block_lines,),
-        in_specs=[line_spec] * 6
-        + [pl.BlockSpec((RULE_COLS, rp), lambda i: (0, 0))],
-        out_specs=line_spec,
-        out_shape=jax.ShapeDtypeStruct((bp, 1), _U32),
-        interpret=interpret,
-    )(
-        field("acl"),
-        field("proto"),
-        field("src"),
-        field("sport"),
-        field("dst"),
-        field("dport"),
-        rules_fm,
-    )
-    return out.reshape(bp)[:b]
+        interpret = not _match.tpu_backend()
+    return _first_match_rows(cols, rules, interpret=interpret)
 
 
-def _ceil_to(n: int, m: int) -> int:
-    return ((n + m - 1) // m) * m
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _first_match_rows(cols: dict, rules: jnp.ndarray, interpret: bool) -> jnp.ndarray:
+    # ra.match: the kernel's custom call (and the staging around it)
+    # carries the stage label the device attribution plane and
+    # match_roofline read (runtime/devprof.py, DESIGN §14)
+    with jax.named_scope("ra.match"):
+        b = cols["acl"].shape[0]
+        r = rules.shape[0]
+        staged = stage_rules(rules)
+        n_blocks = staged.shape[0] // (RULE_BLOCK * RULE_WORDS)
+        rows = -(-b // LANES)
+        rows = -(-rows // TILE_ROWS) * TILE_ROWS
+        block_rows = min(BLOCK_ROWS, rows)
+        rows = -(-rows // block_rows) * block_rows
+
+        def field(name):
+            # padding lines are zeros; their rows are sliced off below
+            v = jnp.pad(cols[name].astype(_U32), (0, rows * LANES - b))
+            return v.reshape(rows, LANES)
+
+        line_spec = pl.BlockSpec((block_rows, LANES), lambda i, j: (i, 0))
+        out = pl.pallas_call(
+            functools.partial(_kernel, n_blocks=n_blocks),
+            grid=(rows // block_rows, n_blocks),
+            in_specs=[
+                pl.BlockSpec(
+                    (RULE_BLOCK * RULE_WORDS,),
+                    lambda i, j: (n_blocks - 1 - j,),
+                    memory_space=pltpu.SMEM,
+                )
+            ]
+            + [line_spec] * len(_FIELDS),
+            out_specs=line_spec,
+            out_shape=jax.ShapeDtypeStruct((rows, LANES), _U32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")
+            ),
+            interpret=interpret,
+        )(staged, *(field(n) for n in _FIELDS))
+        row = out.reshape(rows * LANES)[:b]
+        if n_blocks * RULE_BLOCK != r:
+            # a match on a staging row past R is no match
+            row = jnp.where(row >= _U32(r), _U32(_NO_MATCH), row)
+        return row
 
 
 def match_keys_pallas(
     cols: dict,
     rules: jnp.ndarray,  # [R, RULE_COLS] row-major (DeviceRuleset.rules)
-    rules_fm: jnp.ndarray,  # prep_rules(rules)
     deny_key: jnp.ndarray,
-    block_lines: int = BLOCK_LINES,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Count-key per line via the pallas kernel (ops.match.match_keys twin)."""
-    from .match import rows_to_keys
-
-    row = first_match_rows_pallas(cols, rules_fm, block_lines, interpret)
-    return rows_to_keys(row, rules, deny_key, cols["acl"])
+    """Count-key per line via the kernel (ops.match.match_keys twin)."""
+    row = first_match_rows_pallas(cols, rules, interpret)
+    with jax.named_scope("ra.match"):
+        return rows_to_keys(row, rules, deny_key, cols["acl"])

@@ -31,13 +31,13 @@ from ..config import AnalysisConfig
 from ..models.pipeline import (
     AnalysisState, ChunkOut, DeviceRuleset, DeviceRuleset6,
     DeviceRulesetStacked, DeviceRulesetTenant, V6_ACL_TAG,
-    batch_cols, batch_cols6,
+    batch_cols, batch_cols6, flat_match,
 )
 from ..ops import cms as cms_ops
 from ..ops import counts as count_ops
 from ..ops import hll as hll_ops
 from ..ops import topk as topk_ops
-from ..ops.match import RULE_BLOCK, match_keys, match_keys_stacked
+from ..ops.match import RULE_BLOCK, match_keys_stacked
 from ..runtime import devprof
 
 _U32 = jnp.uint32
@@ -210,22 +210,10 @@ def _core_flat(
     # jaxpr input — the taint source of the weight-linearity proof —
     # instead of a slice of the packed batch.  One definition: the real
     # step and the linter trace this exact function.
-    counts_delta = None
-    if match_impl == "pallas_fused" and ruleset.rules_fm is not None:
-        from ..ops import pallas_fused
-
-        keys, counts_delta = pallas_fused.match_keys_and_counts_pallas(
-            cols, valid, ruleset.rules, ruleset.rules_fm, ruleset.deny_key,
-            n_keys,
-        )
-    elif match_impl == "pallas" and ruleset.rules_fm is not None:
-        from ..ops import pallas_match
-
-        keys = pallas_match.match_keys_pallas(
-            cols, ruleset.rules, ruleset.rules_fm, ruleset.deny_key
-        )
-    else:
-        keys = match_keys(cols, ruleset.rules, ruleset.deny_key, rule_block)
+    keys, counts_delta = flat_match(
+        cols, valid, ruleset, match_impl=match_impl, rule_block=rule_block,
+        n_keys=n_keys,
+    )
     return _merge_tail(
         state, keys, valid, cols["src"], cols["acl"], salt,
         axis=axis, n_keys=n_keys, topk_k=topk_k, exact_counts=exact_counts,

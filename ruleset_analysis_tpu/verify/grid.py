@@ -263,8 +263,9 @@ def _core_kwargs(spec: ProgramSpec) -> dict:
     )
     if spec.kind == "flat":
         kw["match_impl"] = spec.match_impl
-    # the tenant core runs _core_flat on the sliced plane with the XLA
-    # match fixed (make_tenant_step never specializes); no extra kwarg
+    # the tenant core runs _core_flat on the sliced plane with the
+    # backend's scan (models.pipeline.flat_match; make_tenant_step never
+    # specializes); no extra kwarg
     return kw
 
 

@@ -15,7 +15,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from ruleset_analysis_tpu.hostside import aclparse, pack, synth  # noqa: E402
-from ruleset_analysis_tpu.ops import pallas_fused, pallas_match  # noqa: E402
+from ruleset_analysis_tpu.ops import pallas_fused  # noqa: E402
 from ruleset_analysis_tpu.ops.counts import segment_counts  # noqa: E402
 from ruleset_analysis_tpu.ops.match import match_keys  # noqa: E402
 
@@ -47,7 +47,7 @@ def _got(packed, cols, valid, block_lines=512):
     rules = jnp.asarray(packed.rules)
     deny = jnp.asarray(packed.deny_key.astype(np.uint32))
     keys, delta = pallas_fused.match_keys_and_counts_pallas(
-        cols, valid, rules, pallas_match.prep_rules(rules), deny,
+        cols, valid, rules, pallas_fused.prep_rules(rules), deny,
         packed.n_keys, block_lines=block_lines, interpret=True,
     )
     return np.asarray(keys), np.asarray(delta)

@@ -63,9 +63,22 @@ def ruleset():
     return pack.pack_rulesets([rs])
 
 
-def _compile(mesh, make_step, ruleset, rules, batch_shape, batch_spec, label):
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """Trace as the chip does: code that asks for the backend sees a TPU
+    (here it sees the CPU, and would take the CPU's XLA scan)."""
+    from ruleset_analysis_tpu.ops import match as match_ops
+
+    monkeypatch.setattr(match_ops, "tpu_backend", lambda: True)
+    step_lib._cached_step.cache_clear()
+    yield
+    step_lib._cached_step.cache_clear()
+
+
+def _compile(mesh, make_step, ruleset, rules, batch_shape, batch_spec, label,
+             match_impl="xla"):
     """Dispatch one step through the seam and compile its jit for ``mesh``."""
-    cfg = AnalysisConfig(batch_size=batch_shape[-1])
+    cfg = AnalysisConfig(batch_size=batch_shape[-1], match_impl=match_impl)
     rep = NamedSharding(mesh, P())
     state = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
@@ -79,23 +92,58 @@ def _compile(mesh, make_step, ruleset, rules, batch_shape, batch_spec, label):
     return rec.lower(label).compile()
 
 
-def _flat(topo, ruleset, n_chips):
+def _flat(topo, ruleset, n_chips, match_impl="xla"):
     mesh = mesh_lib.make_mesh(topo.devices[:n_chips])
     wire_cols = pack.compact_batch(np.zeros((pack.TUPLE_COLS, 8), np.uint32)).shape[0]
     return _compile(
         mesh, step_lib.make_parallel_step, ruleset, pipeline.ship_ruleset(ruleset),
-        (wire_cols, LINES * n_chips), P(None, "data"), "step.flat",
+        (wire_cols, LINES * n_chips), P(None, "data"), "step.flat", match_impl,
     )
 
 
-def test_flat_step_compiles_one_chip(topo, ruleset):
+def test_flat_step_compiles_one_chip(topo, ruleset, tpu_backend):
     compiled = _flat(topo, ruleset, 1)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    # the TPU's flat step runs the lane-dense scan
+    assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_flat_step_compiles_four_chips_with_all_reduce(topo, ruleset):
+def test_flat_step_compiles_four_chips_with_all_reduce(topo, ruleset, tpu_backend):
     compiled = _flat(topo, ruleset, 4)
-    assert "all-reduce" in compiled.as_text()
+    text = compiled.as_text()
+    assert "all-reduce" in text
+    assert "tpu_custom_call" in text
+
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                     "benchmark")
+
+
+def _bench_module(name):
+    """A module of ``benchmark/`` (a directory of scripts, not a package)."""
+    import importlib
+    import sys
+
+    if BENCH not in sys.path:
+        sys.path.append(BENCH)
+    return importlib.import_module(name)
+
+
+def test_flat_step_kernel_keeps_the_match_scope(topo, ruleset, tpu_backend):
+    """With the kernel forced, the compiled flat step's first-match ops
+    (the kernel's custom call) carry ``ra.match`` in their op metadata:
+    the stage table the benchmark's ``match_roofline`` reads its time by
+    (``benchmark/hlo_scopes.py``) would otherwise find no match time."""
+    import re
+
+    text = _flat(topo, ruleset, 1, match_impl="pallas").as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert calls
+    for line in calls:
+        assert re.search(r'op_name="[^"]*/ra\.match/', line), line
+    _, table = _bench_module("hlo_scopes").scopes_of_module(text)
+    names = {re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", line).group(1) for line in calls}
+    assert {table.get(n) for n in names} == {"match"}
 
 
 def test_v6_step_compiles(topo, ruleset):
@@ -113,16 +161,10 @@ def test_v6_step_compiles(topo, ruleset):
 def dual_ruleset():
     """The ``asa-dual-10k`` cell's unified ACL (10,000 ACEs, about 4,500
     IPv6), drawn by the benchmark's generator and packed by the program."""
-    import importlib
     import json
-    import sys
 
-    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                         "benchmark")
-    if bench not in sys.path:
-        sys.path.append(bench)
-    rules_dual = importlib.import_module("gen.rules_dual")
-    with open(os.path.join(bench, "configs", "asa-dual-10k.json"), encoding="utf-8") as f:
+    rules_dual = _bench_module("gen.rules_dual")
+    with open(os.path.join(BENCH, "configs", "asa-dual-10k.json"), encoding="utf-8") as f:
         rs = rules_dual.make_ruleset(json.load(f))
     return pack.pack_rulesets([aclparse.parse_asa_config(rs.text, rs.firewall)])
 
@@ -159,15 +201,13 @@ def test_pallas_match_kernel_compiles(topo):
     from ruleset_analysis_tpu.ops import pallas_match
 
     one_chip = SingleDeviceSharding(topo.devices[0])
-    rows = 7680
+    rows = 10240  # asa-10k's rule tensor: 10,000 rows padded to 20 rule blocks
     cols = {
         k: jax.ShapeDtypeStruct((LINES,), jnp.uint32, sharding=one_chip)
         for k in ("acl", "proto", "src", "sport", "dst", "dport")
     }
-    rules_fm = jax.ShapeDtypeStruct(
-        (pallas_match.RULE_COLS, rows), jnp.uint32, sharding=one_chip
-    )
-    compiled = pallas_match.first_match_rows_pallas.lower(
-        cols, rules_fm, interpret=False
-    ).compile()
+    rules = jax.ShapeDtypeStruct((rows, pack.RULE_COLS), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(
+        pallas_match.first_match_rows_pallas, static_argnames="interpret"
+    ).lower(cols, rules, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
